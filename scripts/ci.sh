@@ -1,38 +1,12 @@
 #!/usr/bin/env bash
-# Tier-1 CI: optional dev deps, the test suite, and the substrate choke-point
-# invariant (no raw version-sensitive mesh APIs outside src/repro/substrate/).
+# Tier-1 CI on the CPU: the choke-point invariants (no raw mesh APIs outside
+# src/repro/substrate/, ...), the test suite, and the launcher smokes at
+# smoke scale (--smoke).  The chip's own proof is chip_smoke.py.
 set -euo pipefail
 cd "$(dirname "$0")/.."
+export JAX_PLATFORMS=cpu
 
-# jax version pin (ISSUE 4): the substrate + CEFT sweeps are validated on the
-# 0.4.x line and the 0.6+ mesh API; anything else (0.5.x, pre-0.4) fails fast
-# here instead of surfacing as cryptic trace errors mid-suite.  The producing
-# version is also recorded into BENCH_ceft.json metadata by benchmarks/run.py.
-echo "ci: jax version gate (supported window: 0.4.x / 0.6+)"
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python - <<'PY'
-import re
-import sys
-
-import jax
-
-v = jax.__version__
-m = re.match(r"(\d+)\.(\d+)", v)
-mm = (int(m.group(1)), int(m.group(2))) if m else None
-if mm is None or not (mm == (0, 4) or mm >= (0, 6)):
-    sys.exit(f"ci: FAIL -- jax {v} is outside the supported 0.4.x / 0.6+ "
-             "window (0.5.x changed mesh/shard_map semantics mid-flight and "
-             "is not validated; upgrade to 0.6+ or pin 0.4.x)")
-print(f"ci: jax {v} is inside the supported window")
-PY
-
-# optional dev deps -- the suite must also pass without them (property tests
-# auto-skip via tests/_hyp.py), so a failed install is not an error
-if command -v pip >/dev/null 2>&1; then
-    pip install --quiet hypothesis 2>/dev/null \
-        || echo "ci: hypothesis unavailable, property tests will skip"
-fi
-
-echo "ci: forbidden-API grep (version-sensitive mesh calls outside substrate)"
+echo "ci: forbidden-API grep (raw mesh-context API outside substrate)"
 # bare names too, so `from jax import set_mesh` can't sneak past; shard_map
 # is matched only as a jax import/attribute since `from ..substrate import
 # shard_map` is the sanctioned spelling
@@ -49,8 +23,6 @@ echo "ci: choke-point invariant holds"
 # inside models/common.py only -- every other module resolves rules through
 # the active ShardingProfile (sharding_profile context manager / explicit
 # profile= arg), so concurrent engines can't race on a global dict.
-# Validated against jax 0.4.37; the grep itself is version-independent and
-# applies to the whole supported range (0.4.x and the 0.6+ mesh API).
 echo "ci: forbidden-API grep (LOGICAL_RULES outside models/common.py)"
 violations=$(grep -rn "LOGICAL_RULES" src/ tests/ --include='*.py' \
     | grep -v "^src/repro/models/common.py:" || true)
@@ -223,11 +195,12 @@ echo "ci: tier-1 tests"
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q
 
 # Router smoke (ISSUE 5): the CEFT-routed multi-tenant front-end end-to-end
-# on real smoke engines -- two tenants, a two-profile pool, tiny decode.
+# on real smoke engines (--smoke) -- two tenants, a two-profile pool, tiny
+# decode.
 echo "ci: router smoke (repro.launch.serve --router)"
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m repro.launch.serve \
     --router --tenants 2 --pool serve,baseline --requests 2 \
-    --prompt-len 8 --max-new 2 > /dev/null
+    --prompt-len 8 --max-new 2 --smoke > /dev/null
 echo "ci: router smoke ok"
 
 # Planner-registry smoke (ISSUE 10): the same front-end end-to-end with a
@@ -236,7 +209,7 @@ echo "ci: router smoke ok"
 echo "ci: non-CEFT planner smoke (--planner heft --max-split 2)"
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m repro.launch.serve \
     --router --tenants 2 --pool serve,baseline --requests 2 \
-    --prompt-len 8 --max-new 2 --planner heft --max-split 2 \
+    --prompt-len 8 --max-new 2 --planner heft --max-split 2 --smoke \
     | grep "planner=heft" > /dev/null
 echo "ci: non-CEFT planner smoke ok"
 
@@ -251,7 +224,7 @@ echo "ci: chaos smoke (repro.launch.serve --router --chaos-seed)"
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m repro.launch.serve \
     --router --tenants 2 --pool serve,baseline --pool-size 4 --requests 3 \
     --prompt-len 8 --max-new 2 --deadline-factor 3 --chaos-seed 13 \
-    --chaos-rate 0.35 \
+    --chaos-rate 0.35 --smoke \
     | grep "chaos: every admitted request completed exactly once"
 echo "ci: chaos smoke ok"
 

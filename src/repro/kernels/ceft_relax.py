@@ -1,20 +1,31 @@
-"""Fused CEFT level-relaxation Pallas kernel.
+"""Fused CEFT relaxation Pallas kernels.
 
-One kernel invocation relaxes a whole topological level (paper Algorithm 1
-lines 6-18, batched over the level's tasks):
+All three kernels share one VMEM-resident tile contraction
+(:func:`_relax_tile`): for a tile of ``rows`` parent values ``pv`` (rows, P)
+with per-row data volumes ``pdata`` (rows, 1),
 
-    maxk[w, j] = max_d  min_l  pv[w, d, l] + comm(l, j | pdata[w, d])
+    minl[r, j] = min_l  pv[r, l] + comm(l, j | pdata[r])
+    comm(l, j | x) = (L[l] + x / bw[l, j]) * (l != j)
 
-The XLA formulation materializes the (W, D, P, P) candidate tensor in HBM; the
-kernel keeps everything in VMEM: the grid tiles W, and the kernel loops over
-parent slots d, building only a (bw_, P, P) candidate tile per step and folding
-it into a running (masked) max with argmax/argmin bookkeeping for the path
-backtrack.  HBM traffic drops from O(W D P^2) to O(W D P) -- the relaxation is
-turned from memory-bound into VPU-bound (see EXPERIMENTS.md §Perf).
+The parent class ``l`` is the loop axis, so the working set is 2-D (rows on
+sublanes, the child class ``j`` on lanes) and the (rows, P, P) candidate
+tensor of the XLA formulation is never built.  Every tensor the kernels touch
+is 2-D or indexed only on its leading axis, which is what Mosaic lowers:
 
-TPU notes: P is the lane dimension -- pad classes to a multiple of 128 for
-peak efficiency (ops.py handles padding); bw_ (tasks per tile) is the sublane
-dimension, default 8.
+* ``pv[:, l]`` is read as a masked lane-min (exact: ``min`` over one real
+  entry), not a dynamic lane slice;
+* ``bw[l, :]`` and ``L[l]`` are dynamic sublane slices of their refs
+  (``L`` arrives broadcast along the child axis as a (P, P) table, since
+  Mosaic does not broadcast a (1, 1) value over sublanes and lanes at once);
+* per-row scalars (data volumes, validity masks) arrive as (rows, 1) columns,
+  never as rank-1 blocks.
+
+The running min uses a strict ``<`` in ascending ``l``, so ties resolve to
+the first class exactly as ``jnp.argmin`` does in the oracles (``ref.py``).
+
+TPU notes: P is the lane dimension -- the ``ops.py`` wrappers pad classes to
+a multiple of 128; the row tile (edges or tasks per grid step) is the sublane
+dimension, a multiple of 8.
 """
 from __future__ import annotations
 
@@ -25,27 +36,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BIG = 3.0e38  # plain float: jnp scalars would be captured as consts by pallas_call
+INF = float("inf")
+
+
+def _relax_tile(pv, pdata, L_ref, bw_ref):
+    """(minl, argl) of one (rows, P) tile; see the module docstring."""
+    rows, P = pv.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, P), 1)
+    lane_row = jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
+
+    def body(l, carry):
+        run_min, run_arg = carry
+        pvl = jnp.min(jnp.where(lane == l, pv, INF), axis=1, keepdims=True)
+        bwl = bw_ref[pl.ds(l, 1), :]                          # (1, P)
+        Ll = L_ref[pl.ds(l, 1), :]                            # (1, P)
+        off = jnp.where(lane_row == l, 0.0, 1.0).astype(pv.dtype)
+        cand = pvl + (Ll + pdata / bwl) * off                 # (rows, P)
+        upd = cand < run_min
+        return jnp.where(upd, cand, run_min), jnp.where(upd, l, run_arg)
+
+    init = (jnp.full((rows, P), INF, pv.dtype), jnp.zeros((rows, P), jnp.int32))
+    return jax.lax.fori_loop(0, P, body, init)
+
+
+def _edge_relax_kernel(pv_ref, pdata_ref, L_ref, bw_ref, min_ref, argl_ref):
+    """Segment-tiled edge relaxation: one tile = block_e contiguous edges of
+    a level's CSR segment run.  The per-child ``segment_max`` stays in XLA
+    where the scatter is native."""
+    minl, argl = _relax_tile(pv_ref[...], pdata_ref[...], L_ref, bw_ref)
+    min_ref[...] = minl
+    argl_ref[...] = argl
+
+
+def _edge_relax_superstep_kernel(pv_ref, pdata_ref, L_ref, bw_ref, min_ref, argl_ref):
+    """Stacked super-step tile: one grid step relaxes one (level, edge-block)
+    tile of a fused run's stacked (R, E, P) edge tables, with the run (or
+    batch) axis as an outer grid dimension so a whole super-step's
+    relaxation is one ``pallas_call``."""
+    minl, argl = _relax_tile(pv_ref[0], pdata_ref[0], L_ref, bw_ref)
+    min_ref[0] = minl
+    argl_ref[0] = argl
 
 
 def _relax_kernel(pv_ref, pdata_ref, valid_ref, L_ref, bw_ref, max_ref, argk_ref, argl_ref):
-    pv = pv_ref[...]          # (bw_, D, P)
-    pdata = pdata_ref[...]    # (bw_, D)
-    valid = valid_ref[...]    # (bw_, D)
-    L = L_ref[...]            # (P,)
-    bw = bw_ref[...]          # (P, P)
-    W, D, P = pv.shape
-    off = 1.0 - jnp.eye(P, dtype=pv.dtype)
+    """One topological level: the tile contraction per parent slot ``d``,
+    folded into a running masked max with argmax/argmin bookkeeping for the
+    path backtrack.  Parent slots are the leading (untiled) axis of the
+    (D, bw_, ...) blocks, so slot ``d`` is a plain leading-axis read."""
+    D, W, P = pv_ref.shape
 
     def body(d, carry):
         run_max, run_argk, run_argl = carry
-        pvd = jax.lax.dynamic_index_in_dim(pv, d, 1, keepdims=False)      # (W, P)
-        dat = jax.lax.dynamic_index_in_dim(pdata, d, 1, keepdims=False)   # (W,)
-        vd = jax.lax.dynamic_index_in_dim(valid, d, 1, keepdims=False)    # (W,)
-        comm = (L[None, :, None] + dat[:, None, None] / bw[None]) * off   # (W, Pl, Pj)
-        cand = pvd[:, :, None] + comm                                     # (W, Pl, Pj)
-        minl = jnp.min(cand, axis=1)                                      # (W, Pj)
-        argl = jnp.argmin(cand, axis=1).astype(jnp.int32)
-        minl = jnp.where(vd[:, None] > 0, minl, -BIG)
+        minl, argl = _relax_tile(pv_ref[d], pdata_ref[d], L_ref, bw_ref)
+        minl = jnp.where(valid_ref[d] > 0, minl, -BIG)
         upd = minl > run_max  # strict: first maximal parent wins, like argmax
         return (
             jnp.where(upd, minl, run_max),
@@ -54,7 +97,7 @@ def _relax_kernel(pv_ref, pdata_ref, valid_ref, L_ref, bw_ref, max_ref, argk_ref
         )
 
     init = (
-        jnp.full((W, P), -BIG, pv.dtype),
+        jnp.full((W, P), -BIG, pv_ref.dtype),
         jnp.zeros((W, P), jnp.int32),
         jnp.zeros((W, P), jnp.int32),
     )
@@ -64,68 +107,31 @@ def _relax_kernel(pv_ref, pdata_ref, valid_ref, L_ref, bw_ref, max_ref, argk_ref
     argl_ref[...] = run_argl
 
 
-def _edge_relax_kernel(pv_ref, pdata_ref, L_ref, bw_ref, min_ref, argl_ref):
-    """Segment-tiled edge relaxation (ISSUE 3): one tile = block_e contiguous
-    edges of a level's CSR segment run.  Builds only a (block_e, P, P)
-    candidate tile in VMEM -- the O(e·P²) work of the CSR sweep with no
-    (W, D) padding -- and reduces over the parent class in-register.  The
-    per-child ``segment_max`` stays in XLA where the scatter is native."""
-    pv = pv_ref[...]          # (block_e, P)
-    pdata = pdata_ref[...]    # (block_e,)
-    L = L_ref[...]            # (P,)
-    bw = bw_ref[...]          # (P, P)
-    P = pv.shape[1]
-    off = 1.0 - jnp.eye(P, dtype=pv.dtype)
-    comm = (L[None, :, None] + pdata[:, None, None] / bw[None]) * off  # (E,Pl,Pj)
-    cand = pv[:, :, None] + comm                                       # (E,Pl,Pj)
-    min_ref[...] = jnp.min(cand, axis=1)
-    argl_ref[...] = jnp.argmin(cand, axis=1).astype(jnp.int32)
-
-
-def _edge_relax_superstep_kernel(pv_ref, pdata_ref, L_ref, bw_ref, min_ref, argl_ref):
-    """Stacked super-step tile (ISSUE 4): one grid step relaxes one
-    (level, edge-block) tile of a fused run's stacked (R, E, P) edge tables —
-    the same VMEM-resident (block_e, P, P) candidate tile as
-    ``_edge_relax_kernel``, with the run (or batch) axis as an outer grid
-    dimension so a whole super-step's relaxation is one ``pallas_call``."""
-    pv = pv_ref[...][0]       # (block_e, P)
-    pdata = pdata_ref[...][0]  # (block_e,)
-    L = L_ref[...]            # (P,)
-    bw = bw_ref[...]          # (P, P)
-    P = pv.shape[1]
-    off = 1.0 - jnp.eye(P, dtype=pv.dtype)
-    comm = (L[None, :, None] + pdata[:, None, None] / bw[None]) * off  # (E,Pl,Pj)
-    cand = pv[:, :, None] + comm                                       # (E,Pl,Pj)
-    min_ref[...] = jnp.min(cand, axis=1)[None]
-    argl_ref[...] = jnp.argmin(cand, axis=1).astype(jnp.int32)[None]
+def _shared_specs(P: int):
+    """BlockSpecs of the grid-invariant (P, P) L table and bw."""
+    zero = lambda *_: (0, 0)  # noqa: E731  (any grid rank)
+    return [pl.BlockSpec((P, P), zero), pl.BlockSpec((P, P), zero)]
 
 
 @functools.partial(jax.jit, static_argnames=("block_e", "interpret"))
 def edge_relax_superstep_pallas(
-    pv: jnp.ndarray,      # (R, E, P) stacked gathered parent CEFT values, float32
-    pdata: jnp.ndarray,   # (R, E)    data volume per edge, float32
-    L: jnp.ndarray,       # (P,)      float32
-    bw: jnp.ndarray,      # (P, P)    float32
+    pv: jnp.ndarray,      # (R, E, P) stacked gathered parent CEFT values
+    pdata: jnp.ndarray,   # (R, E, 1) data volume per edge
+    L: jnp.ndarray,       # (P, P)    L[l] broadcast along the child axis
+    bw: jnp.ndarray,      # (P, P)
     *,
     block_e: int = 128,
     interpret: bool = False,
 ):
     R, E, P = pv.shape
     assert E % block_e == 0, "pad via ops.edge_relax_superstep"
-    grid = (R, E // block_e)
+    tile = pl.BlockSpec((1, block_e, P), lambda r, i: (r, i, 0))
     return pl.pallas_call(
         _edge_relax_superstep_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_e, P), lambda r, i: (r, i, 0)),
-            pl.BlockSpec((1, block_e), lambda r, i: (r, i)),
-            pl.BlockSpec((P,), lambda r, i: (0,)),
-            pl.BlockSpec((P, P), lambda r, i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_e, P), lambda r, i: (r, i, 0)),
-            pl.BlockSpec((1, block_e, P), lambda r, i: (r, i, 0)),
-        ],
+        grid=(R, E // block_e),
+        in_specs=[tile, pl.BlockSpec((1, block_e, 1), lambda r, i: (r, i, 0)),
+                  *_shared_specs(P)],
+        out_specs=[tile, tile],
         out_shape=[
             jax.ShapeDtypeStruct((R, E, P), pv.dtype),
             jax.ShapeDtypeStruct((R, E, P), jnp.int32),
@@ -136,30 +142,23 @@ def edge_relax_superstep_pallas(
 
 @functools.partial(jax.jit, static_argnames=("block_e", "interpret"))
 def edge_relax_pallas(
-    pv: jnp.ndarray,      # (E, P) gathered parent CEFT values, float32
-    pdata: jnp.ndarray,   # (E,)   data volume per edge, float32
-    L: jnp.ndarray,       # (P,)   float32
-    bw: jnp.ndarray,      # (P, P) float32
+    pv: jnp.ndarray,      # (E, P) gathered parent CEFT values
+    pdata: jnp.ndarray,   # (E, 1) data volume per edge
+    L: jnp.ndarray,       # (P, P) L[l] broadcast along the child axis
+    bw: jnp.ndarray,      # (P, P)
     *,
     block_e: int = 128,
     interpret: bool = False,
 ):
     E, P = pv.shape
     assert E % block_e == 0, "pad via ops.edge_relax"
-    grid = (E // block_e,)
+    tile = pl.BlockSpec((block_e, P), lambda i: (i, 0))
     return pl.pallas_call(
         _edge_relax_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_e, P), lambda i: (i, 0)),
-            pl.BlockSpec((block_e,), lambda i: (i,)),
-            pl.BlockSpec((P,), lambda i: (0,)),
-            pl.BlockSpec((P, P), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_e, P), lambda i: (i, 0)),
-            pl.BlockSpec((block_e, P), lambda i: (i, 0)),
-        ],
+        grid=(E // block_e,),
+        in_specs=[tile, pl.BlockSpec((block_e, 1), lambda i: (i, 0)),
+                  *_shared_specs(P)],
+        out_specs=[tile, tile],
         out_shape=[
             jax.ShapeDtypeStruct((E, P), pv.dtype),
             jax.ShapeDtypeStruct((E, P), jnp.int32),
@@ -170,33 +169,25 @@ def edge_relax_pallas(
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
 def ceft_relax_pallas(
-    pv: jnp.ndarray,      # (W, D, P) float32
-    pdata: jnp.ndarray,   # (W, D)    float32
-    validp: jnp.ndarray,  # (W, D)    float32 mask (1 real parent / 0 padding)
-    L: jnp.ndarray,       # (P,)      float32
-    bw: jnp.ndarray,      # (P, P)    float32
+    pv: jnp.ndarray,      # (D, W, P) parent CEFT values, parent slot leading
+    pdata: jnp.ndarray,   # (D, W, 1) data volume per parent edge
+    validp: jnp.ndarray,  # (D, W, 1) mask (1 real parent / 0 padding)
+    L: jnp.ndarray,       # (P, P)    L[l] broadcast along the child axis
+    bw: jnp.ndarray,      # (P, P)
     *,
     block_w: int = 8,
     interpret: bool = False,
 ):
-    W, D, P = pv.shape
+    D, W, P = pv.shape
     assert W % block_w == 0, "pad via ops.ceft_relax"
-    grid = (W // block_w,)
+    col = pl.BlockSpec((D, block_w, 1), lambda i: (0, i, 0))
+    out = pl.BlockSpec((block_w, P), lambda i: (i, 0))
     return pl.pallas_call(
         _relax_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_w, D, P), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_w, D), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, D), lambda i: (i, 0)),
-            pl.BlockSpec((P,), lambda i: (0,)),
-            pl.BlockSpec((P, P), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_w, P), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, P), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, P), lambda i: (i, 0)),
-        ],
+        grid=(W // block_w,),
+        in_specs=[pl.BlockSpec((D, block_w, P), lambda i: (0, i, 0)), col, col,
+                  *_shared_specs(P)],
+        out_specs=[out, out, out],
         out_shape=[
             jax.ShapeDtypeStruct((W, P), pv.dtype),
             jax.ShapeDtypeStruct((W, P), jnp.int32),

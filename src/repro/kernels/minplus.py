@@ -2,11 +2,12 @@
 
 TPU adaptation of the paper's relaxation hot-spot (DESIGN.md §2): the classic
 (i, j, k) matmul grid with BlockSpec VMEM tiling, accumulating with ``min``
-instead of ``+`` and combining with ``+`` instead of ``*``.  The contraction
-blocks are kept *shallow* (bk << bm, bn) because the (bm, bk, bn) candidate
-tensor must live in VMEM: with (256, 16, 256) fp32 that is 4 MiB -- inside the
-~16 MiB VMEM budget with double buffering, while bm/bn stay multiples of the
-128-lane MXU/VPU tile.
+instead of ``+`` and combining with ``+`` instead of ``*``.  Every block is
+(8, 128)-aligned (bm, bn, bk multiples of 128), and inside a block the
+contraction index is a loop: each step folds ``a[:, k] + b[k, :]`` into the
+2-D (bm, bn) accumulator, so no (bm, bk, bn) candidate tensor is built.
+``a[:, k]`` is read as a masked lane-min (exact), ``b[k, :]`` as a dynamic
+sublane slice of its ref.
 """
 from __future__ import annotations
 
@@ -24,10 +25,14 @@ def _minplus_kernel(a_ref, b_ref, o_ref):
     def _init():
         o_ref[...] = jnp.full_like(o_ref, BIG)
 
-    a = a_ref[...]  # (bm, bk)
-    b = b_ref[...]  # (bk, bn)
-    cand = jnp.min(a[:, :, None] + b[None, :, :], axis=1)
-    o_ref[...] = jnp.minimum(o_ref[...], cand)
+    a = a_ref[...]                                         # (bm, bk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+
+    def body(k, acc):
+        ak = jnp.min(jnp.where(lane == k, a, float("inf")), axis=1, keepdims=True)
+        return jnp.minimum(acc, ak + b_ref[pl.ds(k, 1), :])
+
+    o_ref[...] = jax.lax.fori_loop(0, a.shape[1], body, o_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
@@ -36,7 +41,7 @@ def minplus_pallas(
     b: jnp.ndarray,
     *,
     bm: int = 256,
-    bk: int = 16,
+    bk: int = 128,
     bn: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:

@@ -1,21 +1,23 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
-# count at first init.  Tests/benches never import this module, so they keep
-# seeing the single real CPU device.
-if os.environ.get("REPRO_DRYRUN_DEVICES"):  # test hook: smaller fake fleets
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count="
-        + os.environ["REPRO_DRYRUN_DEVICES"]
-    )
-
 """Multi-pod dry-run (deliverable e): lower + compile every
 (architecture x input-shape x mesh) cell against the production topology,
 record memory/cost/collective analysis for §Dry-run and §Roofline.
 
+A host-CPU tool: the production mesh is a fake fleet of CPU devices, so
+importing this module pins JAX to the CPU and appends the fleet's device
+count (512, or REPRO_DRYRUN_DEVICES) to XLA_FLAGS.  Both must happen before
+jax initializes a backend.  Tests and benches never import this module, so
+they keep seeing the single real CPU device.
+
   python -m repro.launch.dryrun --arch glm4-9b --cell train_4k --mesh single
   python -m repro.launch.dryrun --all --out experiments/dryrun      # driver
 """
+import os
+
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count="
+    + os.environ.get("REPRO_DRYRUN_DEVICES", "512"))))
+
 import argparse
 import json
 import subprocess
@@ -27,6 +29,8 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+jax.config.update("jax_platforms", "cpu")
 
 from .. import configs as C
 from ..models.common import (profile_names, resolve_spec, sharding_profile,

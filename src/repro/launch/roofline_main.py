@@ -1,13 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# env must precede any jax import (same contract as dryrun.py)
-if os.environ.get("REPRO_DRYRUN_DEVICES"):
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count="
-        + os.environ["REPRO_DRYRUN_DEVICES"]
-    )
-
-from repro.launch.roofline import main  # noqa: E402
+"""Roofline CLI on the host-CPU fake fleet: importing repro.launch.dryrun
+first pins JAX to the CPU and sets the fleet's device count, before jax
+initializes a backend."""
+from repro.launch import dryrun  # noqa: F401
+from repro.launch.roofline import main
 
 if __name__ == "__main__":
     main()

@@ -1,7 +1,7 @@
 """Serving launcher: batched greedy generation with any assigned architecture
-(smoke scale on CPU; same engine drives production meshes).
+at its published widths (--smoke: the reduced same-family variant, for CPU).
 
-  PYTHONPATH=src python -m repro.launch.serve --arch granite-3-8b --batch 4
+  PYTHONPATH=src python -m repro.launch.serve --arch granite-3-8b --batch 4 --smoke
 
 Router mode (--router): a CEFT-routed multi-tenant front-end over an elastic
 engine pool (repro.serve.pool); each tick the pending requests are planned
@@ -11,9 +11,13 @@ workers, --backend subprocess puts each worker in its own process with a
 measured comm plane, --autoscale lets the pool grow/drain with queue depth.
 
   PYTHONPATH=src python -m repro.launch.serve --router --tenants 2 \
-      --pool serve,baseline --requests 4 --max-new 4
+      --pool serve,baseline --requests 4 --max-new 4 --smoke
   PYTHONPATH=src python -m repro.launch.serve --router --pool-size 4 \
-      --autoscale --backend subprocess --requests 8
+      --autoscale --backend subprocess --requests 8 --smoke
+
+Every router run checks that each admitted request completed exactly once
+and exits 1 otherwise.  The subprocess backend is for CPU hosts only (each
+worker loads JAX; an accelerator belongs to one process).
 
 Failure containment (--deadline-factor N arms the plan-derived deadline
 watchdog; --chaos-seed S additionally runs the whole thing under the
@@ -21,7 +25,7 @@ deterministic fault injector and asserts every admitted request completed
 exactly once — the local chaos soak):
 
   PYTHONPATH=src python -m repro.launch.serve --router --pool-size 4 \
-      --requests 4 --deadline-factor 3 --chaos-seed 7
+      --requests 4 --deadline-factor 3 --chaos-seed 7 --smoke
 
 SLO plane (--tiers assigns tenants to weighted tiers round-robin; a tier
 with an SLO stamps it on every admitted request, and the router propagates
@@ -29,7 +33,7 @@ it backward through each tick's plan — see docs/cli.md for the full flag
 reference and docs/architecture.md for the request lifecycle):
 
   PYTHONPATH=src python -m repro.launch.serve --router --tenants 3 \
-      --tiers gold:8:2.0,bronze:1 --deadline-factor 3
+      --tiers gold:8:2.0,bronze:1 --deadline-factor 3 --smoke
 """
 import argparse
 import sys
@@ -39,6 +43,7 @@ import numpy as np
 from .. import configs as C
 from ..core.planners import planner_names
 from ..models.common import profile_names
+from ..substrate import enable_compile_cache
 from ..serve import (
     AdmissionQueue,
     Engine,
@@ -68,7 +73,11 @@ def parse_tiers(spec: str) -> list[TenantTier]:
     return tiers
 
 
-def run_router(args) -> None:
+def run_router(args):
+    """Serve ``args.tenants x args.requests`` synthetic requests through the
+    routed pool and print the run summary.  Exits 1 unless every admitted
+    request completed exactly once.  Returns (cfg, router, done) with
+    ``done`` mapping each request id to its tokens."""
     profiles = [p.strip() for p in args.pool.split(",") if p.strip()]
     unknown = [p for p in profiles if p not in profile_names()]
     if unknown:
@@ -77,11 +86,12 @@ def run_router(args) -> None:
     # --pool-size N replicates the profile list round-robin up to N workers
     size = args.pool_size if args.pool_size else len(profiles)
     profiles = [profiles[i % len(profiles)] for i in range(size)]
-    cfg = C.get(args.arch, smoke=True)
+    cfg = C.get(args.arch, smoke=args.smoke)
     if args.backend == "subprocess":
         specs = [WorkerSpec(f"{args.arch}:{p}#{i}", profile=p,
-                            factory="repro.serve.pool:smoke_engine_factory",
-                            args=(args.arch, p), backend="subprocess")
+                            factory="repro.serve.pool:engine_factory",
+                            args=(args.arch, p, args.smoke),
+                            backend="subprocess")
                  for i, p in enumerate(profiles)]
     else:
         specs = [WorkerSpec(f"{args.arch}:{p}#{i}", profile=p,
@@ -187,37 +197,41 @@ def run_router(args) -> None:
         fired = {k: v for k, v in f.items() if k != "calls" and v}
         print(f"chaos: seed={args.chaos_seed} calls={f['calls']} "
               f"fired={fired or 'none'}")
-        # the soak's contract: every admitted request completes EXACTLY once
-        # (zero lost, zero double-completed — duplicates were dropped as
-        # stale), and hedge duplicate work stays bounded by the overdue
-        # critical-path dispatch count
-        admitted = set(tenant_of)
-        missing = sorted(admitted - set(done))
-        ok = True
-        if missing:
-            ok = False
-            print(f"chaos: FAIL {len(missing)} admitted requests never "
-                  f"completed: {missing}")
-        if s["completions"] != len(done):
-            ok = False
-            print(f"chaos: FAIL completion count {s['completions']} != "
-                  f"{len(done)} distinct rids (double-completion)")
-        if s["hedges"] > s["overdue_cp"]:
-            ok = False
-            print(f"chaos: FAIL hedges ({s['hedges']}) exceed overdue "
-                  f"critical-path dispatches ({s['overdue_cp']})")
-        if not ok:
-            sys.exit(1)
-        print(f"chaos: every admitted request completed exactly once "
-              f"({len(done)}/{len(admitted)})")
+    # the serving contract: every admitted request completes EXACTLY once
+    # (zero lost, zero double-completed -- duplicates were dropped as stale);
+    # under chaos, hedge duplicate work also stays bounded by the overdue
+    # critical-path dispatch count
+    tag = "chaos" if chaos is not None else "router"
+    admitted = set(tenant_of)
+    missing = sorted(admitted - set(done))
+    fails = []
+    if missing:
+        fails.append(f"{len(missing)} admitted requests never completed: "
+                     f"{missing}")
+    if s["completions"] != len(done):
+        fails.append(f"completion count {s['completions']} != {len(done)} "
+                     "distinct rids (double-completion)")
+    if chaos is not None and s["hedges"] > s["overdue_cp"]:
+        fails.append(f"hedges ({s['hedges']}) exceed overdue critical-path "
+                     f"dispatches ({s['overdue_cp']})")
+    for msg in fails:
+        print(f"{tag}: FAIL {msg}")
+    if fails:
+        sys.exit(1)
+    print(f"{tag}: every admitted request completed exactly once "
+          f"({len(done)}/{len(admitted)})")
+    return cfg, router, done
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=C.ARCHS, default="granite-3-8b")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument("--smoke", action="store_true",
+                    help="build the reduced same-family config instead of "
+                         "the published widths (CPU-sized)")
     ap.add_argument("--profile", default="serve", choices=profile_names(),
                     help="sharding profile, scoped to this engine")
     ap.add_argument("--router", action="store_true",
@@ -269,12 +283,17 @@ def main():
                          "round-robin; weights drive the admission queue's "
                          "weighted drain, SLOs arm backward deadline "
                          "propagation (e.g. gold:8:2.0,bronze:1)")
-    args = ap.parse_args()
+    return ap
 
+
+def main(argv=None):
+    args = parser().parse_args(argv)
+    enable_compile_cache()
     if args.router:
-        return run_router(args)
+        run_router(args)
+        return
 
-    cfg = C.get(args.arch, smoke=True)
+    cfg = C.get(args.arch, smoke=args.smoke)
     eng = Engine(cfg, profile=args.profile)
     rng = np.random.default_rng(0)
     prompts = rng.integers(2, cfg.vocab, (args.batch, args.prompt_len)).astype(np.int32)

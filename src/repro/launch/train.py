@@ -1,13 +1,15 @@
-"""Training launcher: any assigned architecture (smoke scale on CPU; the same
-code path drives the production meshes on real fleets).
+"""Training launcher: any assigned architecture at its published widths
+(--smoke: the reduced same-family variant, for CPU); the same code path
+drives the production meshes on real fleets.
 
-  PYTHONPATH=src python -m repro.launch.train --arch minicpm-2b --steps 50
+  PYTHONPATH=src python -m repro.launch.train --arch minicpm-2b --steps 50 --smoke
 """
 import argparse
 
 from .. import configs as C
 from ..configs.base import ShapeCell
 from ..models.common import profile_names
+from ..substrate import enable_compile_cache
 from ..train import Trainer, TrainerConfig
 from .mesh import make_test_mesh
 
@@ -20,12 +22,15 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=20)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="build the reduced same-family config instead of "
+                         "the published widths (CPU-sized)")
     ap.add_argument("--resume", action="store_true",
                     help="restore the newest valid checkpoint before training")
     ap.add_argument("--profile", default="opt1", choices=profile_names(),
                     help="sharding profile, scoped to this trainer")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = C.get(args.arch, smoke=args.smoke)
     cell = ShapeCell("cli", seq_len=args.seq, global_batch=args.batch, kind="train")

@@ -4,8 +4,8 @@ from .pool import (
     EngineSlot,
     WorkerLost,
     WorkerSpec,
+    engine_factory,
     null_engine_factory,
-    smoke_engine_factory,
 )
 from .queue import AdmissionQueue, Request, TenantTier, class_mix, workload_class
 from .router import Dispatch, Router, router_machine
@@ -13,5 +13,5 @@ from .watchdog import DeadlineWatchdog
 __all__ = ["AdmissionQueue", "DeadlineWatchdog", "Dispatch", "Engine",
            "EnginePool", "EngineSlot", "Request", "Router", "ServeConfig",
            "TenantTier", "WorkerLost", "WorkerSpec", "class_mix",
-           "null_engine_factory", "router_machine", "smoke_engine_factory",
+           "engine_factory", "null_engine_factory", "router_machine",
            "workload_class"]

@@ -40,7 +40,11 @@ class Engine:
         with sharding_profile(self.profile):
             self.params = params if params is not None else self.model.init(
                 jax.random.PRNGKey(seed))
-        self._decode = jax.jit(self.model.decode)
+        # the decode cache is donated off-CPU: each step's new cache reuses
+        # the old one's device buffer instead of holding both (the CPU
+        # backend cannot donate, and would warn on every step)
+        donate = () if jax.default_backend() == "cpu" else (1,)
+        self._decode = jax.jit(self.model.decode, donate_argnums=donate)
         self._prefill = jax.jit(self.model.prefill)
 
     # ------------------------------------------------------------------ cache
@@ -75,15 +79,26 @@ class Engine:
         with sharding_profile(self.profile):
             return self._generate(prompts, scfg)
 
+    def prefill(self, prompts: np.ndarray):
+        """prompts: (B, P) int32.  Returns (prefill cache, last-token logits
+        (B, 1, vocab))."""
+        with sharding_profile(self.profile):
+            return self._prefill_batch(prompts)
+
+    def _prefill_batch(self, prompts: np.ndarray):
+        batch = {"tokens": jnp.asarray(prompts, jnp.int32)}
+        if self.cfg.family == "encdec":
+            batch["frames"] = jnp.zeros(
+                (prompts.shape[0], self.cfg.enc_seq, self.cfg.d_model),
+                jnp.float32)
+        return self._prefill(self.params, batch)
+
     def _generate(self, prompts: np.ndarray, scfg: ServeConfig | None = None):
         scfg = scfg or ServeConfig()
         cfg = self.cfg
         B, P = prompts.shape
         total = P + scfg.max_new_tokens
-        batch = {"tokens": jnp.asarray(prompts, jnp.int32)}
-        if cfg.family == "encdec":
-            batch["frames"] = jnp.zeros((B, cfg.enc_seq, cfg.d_model), jnp.float32)
-        pf_cache, logits = self._prefill(self.params, batch)
+        pf_cache, logits = self._prefill_batch(prompts)
         if cfg.family == "encdec":
             cache = {"self": self._seed_cache(
                 {"pos0": pf_cache["self"]}, B, total, P)["pos0"],

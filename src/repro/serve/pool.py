@@ -17,7 +17,9 @@ Two worker backends:
   pickle-over-pipe protocol (``init`` / ``generate`` / ``probe`` / ``ping``
   / ``close``).  The engine is built inside the child from a
   ``"module:callable"`` factory path, so the parent never pickles live
-  engines.  A dead pipe surfaces as :class:`WorkerLost`.
+  engines.  A dead pipe surfaces as :class:`WorkerLost`.  CPU-only: each
+  child loads JAX, so a parent on an accelerator refuses to start them
+  rather than let them race it for the chip.
 
 Comm-plane measurement: with ``probe="measure"`` (or an injected callable,
 for determinism in tests) the pool times a payload transfer leg per worker —
@@ -120,11 +122,12 @@ def null_engine_factory():
     return _Null()
 
 
-def smoke_engine_factory(arch: str, profile: str):
-    """A real smoke-scale Engine for subprocess workers (built in the child)."""
+def engine_factory(arch: str, profile: str, smoke: bool = False):
+    """A real Engine for subprocess workers (built in the child): the
+    published config, or its reduced same-family variant with ``smoke``."""
     from .. import configs as C
     from .engine import Engine
-    return Engine(C.get(arch, smoke=True), profile=profile)
+    return Engine(C.get(arch, smoke=smoke), profile=profile)
 
 
 # ----------------------------------------------------------------- transport
@@ -256,6 +259,16 @@ class _SubprocWorker:
         if not spec.factory:
             raise ValueError(f"subprocess worker {spec.name!r} needs a "
                              "'module:callable' factory path")
+        import jax
+
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"subprocess worker {spec.name!r}: this process already uses "
+                f"the {backend!r} backend, and every worker process would load "
+                "JAX too -- an accelerator belongs to one process at a time, "
+                "so the workers would race this process for it.  Use the "
+                "inproc backend on an accelerator host.")
         self._name, self._index = spec.name, index
         self.stats = stats if stats is not None else {"stale_replies": 0}
         child_env = dict(os.environ)

@@ -868,6 +868,7 @@ class Router:
                         return
                     with lock:
                         done.update(out)
+                        self.stats["completions"] += len(out)
 
             threads = [threading.Thread(target=worker,
                                         args=(self.slots[eng].name, ds))

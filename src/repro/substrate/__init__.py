@@ -1,14 +1,9 @@
-"""Version-portable device-mesh and sharding substrate.
+"""Device-mesh and sharding substrate.
 
-Single choke point for every JAX API that changed across the 0.4.x -> 0.6+
-mesh redesign (``jax.set_mesh``, ``jax.sharding.AxisType``,
-``jax.sharding.get_abstract_mesh``, ``jax.sharding.use_mesh``).  No module
-outside this package may touch those names directly -- scripts/ci.sh greps
-for violations.
-
-All feature detection happens at call time (``getattr`` on the live jax
-modules), so tests can monkeypatch either API generation onto the installed
-jax and the substrate follows.
+Single choke point for the JAX mesh-context API (``jax.set_mesh``,
+``jax.sharding.AxisType``, ``jax.sharding.get_abstract_mesh``,
+``jax.shard_map``).  No module outside this package may touch those names
+directly -- scripts/ci.sh greps for violations.
 """
 from .compat import (
     compiled_cost_analysis,
@@ -17,8 +12,8 @@ from .compat import (
     current_abstract_mesh,
     current_axis_sizes,
     degrade_spec,
+    enable_compile_cache,
     host_id,
-    jax_mesh_api,
     make_mesh,
     mesh_axis_sizes,
     mesh_context,
@@ -33,8 +28,8 @@ __all__ = [
     "current_abstract_mesh",
     "current_axis_sizes",
     "degrade_spec",
+    "enable_compile_cache",
     "host_id",
-    "jax_mesh_api",
     "make_mesh",
     "mesh_axis_sizes",
     "mesh_context",

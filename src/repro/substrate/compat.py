@@ -1,15 +1,13 @@
-"""Feature-detected mesh/sharding implementations for both JAX generations.
+"""Mesh/sharding substrate on the installed JAX (0.9): the single call sites
+for the mesh-context API (``jax.set_mesh``, ``jax.sharding.AxisType``,
+``jax.sharding.get_abstract_mesh``, ``jax.shard_map``).
 
-Generation map (all resolved per call, never cached, so monkeypatching the
-jax module flips the substrate):
-
-    operation             modern (>= 0.6)                     legacy (0.4.x)
-    -------------------   ---------------------------------   ------------------------------
-    make_mesh             jax.make_mesh(axis_types=Auto...)   jax.make_mesh / Mesh(reshape)
-    mesh_context          jax.set_mesh / sharding.use_mesh    Mesh.__enter__
-    current_abstract_mesh sharding.get_abstract_mesh          pxla thread_resources physical
-    constrain             with_sharding_constraint            with_sharding_constraint
-                          (no-op when no mesh is active, both generations)
+    operation             implementation
+    -------------------   ---------------------------------------------
+    make_mesh             jax.make_mesh(axis_types=Auto...)
+    mesh_context          jax.set_mesh
+    current_abstract_mesh jax.sharding.get_abstract_mesh (None when empty)
+    constrain             with_sharding_constraint, a no-op with no mesh
 """
 from __future__ import annotations
 
@@ -17,19 +15,12 @@ import contextlib
 import math
 import os
 import socket
+from pathlib import Path
 from typing import Any, Iterator, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec
-
-
-def jax_mesh_api() -> str:
-    """'modern' when the >=0.6 mesh-context API is present, else 'legacy'."""
-    if getattr(jax, "set_mesh", None) is not None or \
-            getattr(jax.sharding, "use_mesh", None) is not None:
-        return "modern"
-    return "legacy"
+from jax.sharding import AxisType, Mesh, PartitionSpec
 
 
 # ------------------------------------------------------------------ make_mesh
@@ -37,9 +28,8 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices: Sequence[Any] | None = None) -> Mesh:
     """Build a Mesh of `shape` over `axes`, optionally from explicit devices.
 
-    On modern JAX the axes are marked AxisType.Auto (the compiler keeps full
-    sharding freedom, matching 0.4.x semantics).  Raises RuntimeError when
-    fewer devices exist than the shape needs.
+    The axes are AxisType.Auto (the compiler keeps full sharding freedom).
+    Raises RuntimeError when fewer devices exist than the shape needs.
     """
     shape = tuple(int(s) for s in shape)
     axes = tuple(axes)
@@ -47,12 +37,8 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
     devs = np.asarray(devices if devices is not None else jax.devices()).ravel()
     if devs.size < n:
         raise RuntimeError(f"need {n} devices, have {devs.size}")
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    mk = getattr(jax, "make_mesh", None)
-    if axis_type is not None and mk is not None:
-        return mk(shape, axes, devices=list(devs[:n]),
-                  axis_types=(axis_type.Auto,) * len(axes))
-    return Mesh(devs[:n].reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=list(devs[:n]),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:
@@ -63,33 +49,15 @@ def mesh_axis_sizes(mesh) -> dict[str, int]:
 @contextlib.contextmanager
 def mesh_context(mesh: Mesh) -> Iterator[Mesh]:
     """Activate `mesh` for jit tracing / sharding constraints in this block."""
-    setter = getattr(jax, "set_mesh", None) or \
-        getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def current_abstract_mesh():
-    """The mesh active for the current trace, or None when there is none.
-
-    Modern JAX reports the abstract mesh; legacy JAX the physical mesh from
-    the thread-local resource env.  Both expose .shape / .axis_names, which
-    is all callers may rely on.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        am = getter()
-        if am is None or am.empty:
-            return None
-        return am
-    from jax.interpreters import pxla
-
-    pm = pxla.thread_resources.env.physical_mesh
-    return None if pm.empty else pm
+    """The abstract mesh active for the current trace, or None when there is
+    none.  Callers may rely on .shape / .axis_names only."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am is None or am.empty else am
 
 
 def current_axis_sizes() -> dict[str, int] | None:
@@ -109,52 +77,44 @@ def process_topology() -> dict:
     pool probes through: same pid => in-process transfer, same host / other
     pid => pipe transport, other host => network (future).
 
-    Accelerator facts are best-effort: they initialize the jax backend, and a
-    worker that cannot (or a caller probing before backend setup) still gets
-    the host/process identity.
+    Reading the platform initializes the jax backend; a backend that fails
+    to initialize raises here rather than reporting a device-less process.
     """
-    info: dict = {"host": host_id(), "pid": os.getpid(),
-                  "n_cpus": os.cpu_count() or 1}
-    try:
-        info["platform"] = jax.default_backend()
-        info["n_devices"] = jax.device_count()
-    except Exception:  # pragma: no cover - backend init failure
-        info["platform"] = None
-        info["n_devices"] = 0
-    return info
+    return {"host": host_id(), "pid": os.getpid(),
+            "n_cpus": os.cpu_count() or 1,
+            "platform": jax.default_backend(),
+            "n_devices": jax.device_count()}
+
+
+# ------------------------------------------------------- compilation cache
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at a fixed place and return
+    the directory in use.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    reads it itself and nothing is set here; otherwise the cache goes to
+    ``<repo>/.jax_cache`` -- a fixed path, since a directory that moves
+    never hits.  Entry points call this from ``main()``, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ------------------------------------------------------------- cost analysis
 def compiled_cost_analysis(compiled) -> dict:
-    """Normalize Compiled.cost_analysis() across generations.
-
-    0.4.x returns a one-element list of dicts (one per program); modern JAX
-    returns the dict directly.  Always returns a dict ({} when XLA offers no
-    analysis).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    """Compiled.cost_analysis() as a dict ({} when XLA offers no analysis)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 # ----------------------------------------------------------------- shard_map
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
-    """shard_map across generations, replication checking off.
-
-    Modern JAX: jax.shard_map (check_vma, earlier check_rep).  Legacy:
-    jax.experimental.shard_map.shard_map (check_rep).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-
-    import inspect
-
-    params = inspect.signature(sm).parameters
-    check_kw = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{check_kw: False})
+    """jax.shard_map with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ----------------------------------------------------------------- constrain

@@ -154,6 +154,7 @@ class Trainer:
                     params, opt_state = p_like, o_like
                     step = 1
         self._save(self.tcfg.steps, params, opt_state)
+        self.params = params  # the trained parameters, as laid out on the mesh
         return self.metrics
 
     # -------------------------------------------------------------- straggler

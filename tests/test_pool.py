@@ -178,6 +178,21 @@ def test_topology_reported_through_substrate_seam():
         assert t["host"] == here["host"] and t["pid"] == os.getpid()
 
 
+def test_topology_raises_on_backend_failure(monkeypatch):
+    """A backend that fails to initialize surfaces from the topology probe
+    instead of being reported as a device-less process."""
+    import jax
+
+    from repro.substrate import process_topology
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        process_topology()
+
+
 # -------------------------------------------------------- failure semantics
 def test_worker_loss_degrades_column_and_fails_over():
     """Acceptance (ISSUE 7): killing a worker under load completes the
@@ -351,6 +366,17 @@ def test_subprocess_worker_roundtrip_and_measured_plane():
         assert pool.stats["probes"] >= 1
     finally:
         pool.close()
+
+
+def test_subprocess_backend_refuses_accelerator_parent(monkeypatch):
+    """A parent on an accelerator must not start JAX-loading children that
+    would race it for the chip: the pool refuses before spawning any."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="inproc backend"):
+        EnginePool([WorkerSpec("w0", factory="repro.serve.pool:null_engine_factory",
+                               backend="subprocess")])
 
 
 def test_subprocess_worker_death_surfaces_as_worker_lost():

@@ -1,15 +1,16 @@
-"""Substrate tests: the version-portable mesh/sharding compat layer (both
-JAX API generations), optimizer, schedules, checkpointing, data determinism,
-gradient compression."""
-import contextlib
+"""Substrate tests: the mesh/sharding layer on the installed JAX, the
+chip-only smoke script's refusal on the CPU, optimizer, schedules,
+checkpointing, data determinism, gradient compression."""
 import os
-import types
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec
 
 from repro import checkpoint as ckpt
 from repro import substrate
@@ -17,22 +18,10 @@ from repro.data import DataConfig, SyntheticLM
 from repro.optim import AdamW, warmup_cosine, wsd
 from repro.optim.grad_compress import ef_quantize, ef_quantize_tree, init_ef
 
-
-# ---------------------------------------------------- mesh/sharding compat
-def test_legacy_generation_native():
-    """On jax 0.4.x none of the modern attrs exist; the substrate must run
-    entirely on Mesh.__enter__ + thread-local resources."""
-    if hasattr(jax, "set_mesh") or hasattr(jax.sharding, "use_mesh"):
-        pytest.skip("installed jax is modern; legacy path covered via fakes")
-    assert substrate.jax_mesh_api() == "legacy"
-    mesh = substrate.make_mesh((1, 1), ("data", "model"))
-    assert substrate.mesh_axis_sizes(mesh) == {"data": 1, "model": 1}
-    assert substrate.current_abstract_mesh() is None
-    with substrate.mesh_context(mesh):
-        assert substrate.current_axis_sizes() == {"data": 1, "model": 1}
-    assert substrate.current_axis_sizes() is None
+REPO = Path(__file__).resolve().parent.parent
 
 
+# ---------------------------------------------------- mesh/sharding substrate
 def test_make_mesh_insufficient_devices():
     with pytest.raises(RuntimeError, match="devices"):
         substrate.make_mesh((1024, 64), ("data", "model"))
@@ -54,97 +43,44 @@ def test_constrain_under_active_mesh_jit():
     np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
 
-class _FakeAbstractMesh:
-    def __init__(self, sizes):
-        self._sizes = dict(sizes)
-
-    @property
-    def empty(self):
-        return not self._sizes
-
-    @property
-    def shape(self):
-        return dict(self._sizes)
-
-    @property
-    def axis_names(self):
-        return tuple(self._sizes)
-
-
-def _install_modern_fakes(monkeypatch, calls, state):
-    """Simulate the >=0.6 API generation on whatever jax is installed."""
-
-    @contextlib.contextmanager
-    def fake_set_mesh(mesh):
-        calls.setdefault("set_mesh", []).append(mesh)
-        prev = state["mesh"]
-        state["mesh"] = mesh
-        try:
-            yield mesh
-        finally:
-            state["mesh"] = prev
-
-    def fake_get_abstract_mesh():
-        m = state["mesh"]
-        return _FakeAbstractMesh({} if m is None else m.shape)
-
-    def fake_make_mesh(shape, axes, *, devices=None, axis_types=None):
-        calls["make_mesh"] = {"shape": tuple(shape), "axes": tuple(axes),
-                              "axis_types": axis_types}
-        return _FakeAbstractMesh(dict(zip(axes, shape)))
-
-    def fake_wsc(x, spec):
-        calls.setdefault("wsc", []).append(spec)
-        return x
-
-    fake_axis_type = types.SimpleNamespace(Auto="auto")
-    monkeypatch.setattr(jax, "set_mesh", fake_set_mesh, raising=False)
-    monkeypatch.setattr(jax, "make_mesh", fake_make_mesh, raising=False)
-    monkeypatch.setattr(jax.sharding, "AxisType", fake_axis_type, raising=False)
-    monkeypatch.setattr(jax.sharding, "get_abstract_mesh",
-                        fake_get_abstract_mesh, raising=False)
-    monkeypatch.setattr(jax.lax, "with_sharding_constraint", fake_wsc)
-    return fake_axis_type
-
-
-def test_modern_generation_routing(monkeypatch):
-    calls, state = {}, {"mesh": None}
-    fake_axis_type = _install_modern_fakes(monkeypatch, calls, state)
-    assert substrate.jax_mesh_api() == "modern"
-
+def test_modern_generation_routing():
+    """make_mesh builds Auto axes; mesh_context activates the mesh for the
+    abstract-mesh queries and restores 'no mesh' on exit."""
     mesh = substrate.make_mesh((1, 1), ("data", "model"))
-    assert calls["make_mesh"]["axis_types"] == (fake_axis_type.Auto,) * 2
-    assert substrate.mesh_axis_sizes is not None  # unchanged helper
-
-    assert substrate.current_abstract_mesh() is None  # empty abstract mesh
+    assert mesh.axis_types == (AxisType.Auto,) * 2
+    assert substrate.mesh_axis_sizes(mesh) == {"data": 1, "model": 1}
+    assert substrate.current_abstract_mesh() is None
     with substrate.mesh_context(mesh):
-        assert calls["set_mesh"] == [mesh]
+        assert substrate.current_abstract_mesh().axis_names == ("data", "model")
         assert substrate.current_axis_sizes() == {"data": 1, "model": 1}
     assert substrate.current_axis_sizes() is None
 
 
-def test_modern_constrain_divisibility_degradation(monkeypatch):
-    calls, state = {}, {"mesh": None}
-    _install_modern_fakes(monkeypatch, calls, state)
-    mesh = _FakeAbstractMesh({"data": 2, "model": 4})
-    x = np.ones((4, 6), np.float32)
-    with substrate.mesh_context(mesh):
-        substrate.constrain(x, "data", "model")
-    # dim0=4 divides data=2; dim1=6 does not divide model=4 -> dropped
-    assert calls["wsc"] == [PartitionSpec("data", None)]
+def test_modern_constrain_divisibility_degradation():
+    """Under a (data=2, model=4) mesh a (4, 6) array keeps 'data' on dim 0
+    and drops 'model' from dim 1 (6 % 4 != 0).  An abstract mesh suffices to
+    trace the constraint, so no fake devices are needed."""
+    am = AbstractMesh((2, 4), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
+    with jax.sharding.use_abstract_mesh(am):
+        jaxpr = jax.make_jaxpr(
+            lambda a: substrate.constrain(a, "data", "model"))(jnp.ones((4, 6)))
+    (eqn,) = jaxpr.eqns
+    assert eqn.primitive.name == "sharding_constraint"
+    assert eqn.params["sharding"].spec == PartitionSpec("data", None)
 
 
-def test_shard_map_modern_kwarg_detection(monkeypatch):
-    captured = {}
-
-    def fake_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        captured.update(mesh=mesh, check_vma=check_vma)
-        return f
-
-    monkeypatch.setattr(jax, "shard_map", fake_shard_map, raising=False)
-    out = substrate.shard_map(lambda a: a, mesh="m", in_specs=(), out_specs=())
-    assert captured == {"mesh": "m", "check_vma": False}
-    assert callable(out)
+def test_shard_map_modern_kwarg_detection():
+    """The substrate turns replication checking off: an out_spec that asks
+    for replication the checker cannot infer is accepted, where the checked
+    jax.shard_map refuses it."""
+    mesh = substrate.make_mesh((1,), ("data",))
+    kw = dict(mesh=mesh, in_specs=(PartitionSpec("data"),),
+              out_specs=PartitionSpec())
+    f = substrate.shard_map(lambda a: a + 1, **kw)
+    np.testing.assert_array_equal(np.asarray(f(jnp.ones(4))), 2 * np.ones(4))
+    with pytest.raises(ValueError, match="replication"):
+        jax.shard_map(lambda a: a + 1, **kw)(jnp.ones(4))
 
 
 def test_shard_map_legacy_executes():
@@ -153,6 +89,38 @@ def test_shard_map_legacy_executes():
                             in_specs=(PartitionSpec(),),
                             out_specs=PartitionSpec())
     np.testing.assert_array_equal(np.asarray(f(jnp.ones(4))), 2 * np.ones(4))
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """chip_smoke.py is a chip-only proof: on the CPU it exits non-zero
+    before any phase and prints no ok line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, str(REPO / "chip_smoke.py")],
+                       env=env, capture_output=True, text=True, timeout=120)
+    assert r.returncode != 0, r.stdout + r.stderr
+    assert '"ok": true' not in r.stdout
+    assert "no TPU" in r.stderr, r.stderr
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir_from_env_or_fixed(from_env, monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set the helper leaves JAX's own
+    setting alone; otherwise the cache goes to the fixed <repo>/.jax_cache."""
+    prev = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = substrate.enable_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            assert got == str(REPO / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_adamw_converges_on_quadratic():
