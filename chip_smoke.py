@@ -11,19 +11,22 @@ One-chip phases:
   (critical-path length and backtracked path), then 8 re-planning scenarios
   in one batched sweep, checked against a single sweep per scenario;
 * serving -- the router launcher's own function, as for ``python -m
-  repro.launch.serve --router --arch minicpm-2b --pool serve --tenants 2
-  --requests 2 --prompt-len 128 --max-new 16``: minicpm-2b at published
-  widths behind the CEFT router, every admitted request completed exactly
-  once, every token in the vocabulary, prefill logits finite;
+  repro.launch.serve --router --arch minicpm-2b --pool serve --batch 2
+  --tenants 2 --requests 2 --prompt-len 128 --max-new 16``: minicpm-2b at
+  published widths behind the CEFT router, every admitted request completed
+  exactly once, every token in the vocabulary, prefill logits finite.
+  ``--batch 2`` caps the micro-batch: with float32 weights the decode step
+  fits one v5e at batch 2 (15.40 GiB) but not at batch 4 (16.11 GiB);
 * reference -- minicpm-2b widths cut to 2 layers: last-token prefill logits
   on the chip against the same weights on the host CPU in float32 with
   "highest" matmul precision, max |chip - cpu| / max |cpu| held to
   REFERENCE_BOUND.
 
 --four-chips runs only the sharded Trainer (minicpm-2b widths, 2 layers,
-seq 512, batch 8, 3 steps) on a (data=1, model=4) mesh against the same run
-on one chip: per-step losses within LOSS_BOUND, and each chip holding about
-a quarter of the sharded parameter bytes.
+seq 512, batch 8, 3 steps at peak learning rate TRAIN_LR) on a (data=1,
+model=4) mesh against the same run on one chip: per-step losses within
+LOSS_BOUND, and each chip holding about a quarter of the sharded parameter
+bytes.
 
 Everything runs in this one process: a chip belongs to one process at a
 time.  With no TPU the script exits non-zero before any phase.  Times on the
@@ -44,13 +47,21 @@ from pathlib import Path
 
 import numpy as np
 
+# seeds the graphs, weights and prompts; the bounds below were set with it
+SEED = 0
 # bf16-vs-f32 last-token logits, max |err| / max |ref|: the same check run
 # on the CPU (bf16 compute against float32 "highest") gives 0.0144-0.0165
 # for seeds 0-2; the bound leaves 3x room for the chip's own bf16 rounding
 REFERENCE_BOUND = 0.05
-# per-step |loss(1 chip) - loss(4 chips)| / loss(1 chip): the model-axis
-# split reorders bf16 partial sums, nothing else differs
-LOSS_BOUND = 1e-2
+# peak learning rate of the 3 training steps: the trainer's smoke-scale 5e-3
+# sends the 2-layer cut at published width from 11.3 to 18.9 at step 3
+TRAIN_LR = 3e-4
+# per-step |loss(1 chip) - loss(4 chips)| / loss(1 chip).  The model-axis
+# split reorders partial sums, nothing else differs: 3.1e-7, 7.1e-7 and
+# 2.9e-5 at steps 1-3 on four v5e chips.  Dropping the MLP down-projection's
+# model-axis reduction moves the step-1 loss by 3.0e-3 there, which a bound
+# of 1e-2 let through
+LOSS_BOUND = 3e-4
 # each chip's share of the sharded parameter bytes may stray this far from 1/4
 SHARE_SLACK = 0.1
 
@@ -67,12 +78,12 @@ def check(cond: bool, msg: str) -> None:
 
 
 # ------------------------------------------------------------------ planner
-def planner_phase(seed: int) -> None:
+def planner_phase() -> None:
     from repro.core import Machine, ceft
     from repro.core.ceft_jax import ceft_batch_csr_results, ceft_jax_csr
     from repro.graphs import rgg
 
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     n, P = 16384, 64
     wl = rgg("high", n, P, rng, o=4, alpha=0.75, beta=50)
     g, comp, m = wl.graph, wl.comp, wl.machine
@@ -117,8 +128,8 @@ def serving_phase() -> None:
     from repro.launch import serve
 
     argv = ["--router", "--arch", "minicpm-2b", "--pool", "serve",
-            "--tenants", "2", "--requests", "2", "--prompt-len", "128",
-            "--max-new", "16"]
+            "--batch", "2", "--tenants", "2", "--requests", "2",
+            "--prompt-len", "128", "--max-new", "16"]
     args = serve.parser().parse_args(argv)
     t0 = time.perf_counter()
     cfg, router, done = serve.run_router(args)  # exits 1 unless exactly once
@@ -144,7 +155,7 @@ def serving_phase() -> None:
 
 
 # ---------------------------------------------------------------- reference
-def reference_phase(seed: int) -> None:
+def reference_phase() -> None:
     import jax
 
     from repro import configs as C
@@ -153,8 +164,8 @@ def reference_phase(seed: int) -> None:
     cfg = dataclasses.replace(C.get("minicpm-2b"), n_layers=2)
     model = build(cfg)
     chip, cpu = jax.devices()[0], jax.devices("cpu")[0]
-    params = model.init(jax.random.PRNGKey(seed))
-    tokens = np.random.default_rng(seed).integers(2, cfg.vocab, (2, 128))
+    params = model.init(jax.random.PRNGKey(SEED))
+    tokens = np.random.default_rng(SEED).integers(2, cfg.vocab, (2, 128))
     tokens = tokens.astype(np.int32)
     _, got = jax.jit(model.prefill)(
         params, {"tokens": jax.device_put(tokens, chip)})
@@ -173,7 +184,7 @@ def reference_phase(seed: int) -> None:
 
 
 # --------------------------------------------------------------- four chips
-def four_chip_phase(seed: int) -> None:
+def four_chip_phase() -> None:
     import jax
 
     from repro import configs as C
@@ -189,7 +200,8 @@ def four_chip_phase(seed: int) -> None:
         ckpt = tempfile.mkdtemp(prefix="chip_smoke_ckpt_")
         try:
             tcfg = TrainerConfig(steps=3, ckpt_every=10**9, ckpt_dir=ckpt,
-                                 log_every=1, seed=seed, profile="opt1")
+                                 log_every=1, seed=SEED, peak_lr=TRAIN_LR,
+                                 profile="opt1")
             t0 = time.perf_counter()
             tr = Trainer(cfg, cell, tcfg, lambda n=n: make_test_mesh(n))
             metrics = tr.run()
@@ -231,8 +243,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the 1-vs-4-chip sharded training check")
-    ap.add_argument("--seed", type=int, default=0,
-                    help="seed for the graphs, weights and prompts")
     args = ap.parse_args(argv)
 
     import jax
@@ -259,11 +269,9 @@ def main(argv=None) -> int:
 
     jax.monitoring.register_event_listener(on_event)
 
-    phases = ([("four_chips", lambda: four_chip_phase(args.seed))]
-              if args.four_chips else
-              [("planner", lambda: planner_phase(args.seed)),
-               ("serving", serving_phase),
-               ("reference", lambda: reference_phase(args.seed))])
+    phases = ([("four_chips", four_chip_phase)] if args.four_chips else
+              [("planner", planner_phase), ("serving", serving_phase),
+               ("reference", reference_phase)])
     for name, run in phases:
         t0 = time.perf_counter()
         try:

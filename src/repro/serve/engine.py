@@ -40,11 +40,7 @@ class Engine:
         with sharding_profile(self.profile):
             self.params = params if params is not None else self.model.init(
                 jax.random.PRNGKey(seed))
-        # the decode cache is donated off-CPU: each step's new cache reuses
-        # the old one's device buffer instead of holding both (the CPU
-        # backend cannot donate, and would warn on every step)
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        self._decode = jax.jit(self.model.decode, donate_argnums=donate)
+        self._decode = jax.jit(self.model.decode)
         self._prefill = jax.jit(self.model.prefill)
 
     # ------------------------------------------------------------------ cache

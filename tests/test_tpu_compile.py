@@ -24,6 +24,7 @@ from repro.core.ceft_jax import _superstep_fns_for, xla_edge_relax
 from repro.kernels import ops
 from repro.models.common import abstract_params
 from repro.models.model import build
+from repro.serve.engine import Engine
 
 V5E_HBM_BYTES = 15.75 * 2**30  # what the compiler reports usable on one v5e
 
@@ -111,22 +112,24 @@ def _total_bytes(compiled) -> int:
 def test_minicpm_engine_step_fits_one_v5e(step, shape_of):
     """The serving engine's steps at published width (2.7B params kept in
     float32): prefill of 4 x 128-token prompts, and decode against a
-    144-token cache (128 + 16 new) with the cache donated, as the Engine
-    compiles it off-CPU.  Decode runs at batch 2, the largest micro-batch
-    of chip_smoke.py's router run (two requests per prompt length): at
-    batch 4 the step needs 16.11 GiB, because it makes a bfloat16 copy of
-    the whole float32 weight stack (4.88 GB)."""
+    144-token cache (128 + 16 new), each compiled from the Engine's own
+    jitted step (abstract weights: nothing is allocated).  Decode runs at
+    batch 2, the largest micro-batch chip_smoke.py's router run may
+    coalesce (``--batch 2``): at batch 4 the step needs 16.11 GiB, because
+    it makes a bfloat16 copy of the whole float32 weight stack (4.88 GB)."""
     cfg = C.get("minicpm-2b")
-    model = build(cfg)
-    params = jax.tree.map(lambda a: shape_of(a.shape, a.dtype), model.abstract())
+    params = jax.tree.map(lambda a: shape_of(a.shape, a.dtype),
+                          build(cfg).abstract())
+    engine = Engine(cfg, params=params)
     if step == "prefill":
-        compiled = _compile(model.prefill, params,
-                            {"tokens": shape_of((4, 128), jnp.int32)})
+        compiled = engine._prefill.lower(
+            params, {"tokens": shape_of((4, 128), jnp.int32)}).compile()
     else:
         B = 2
-        cache = jax.tree.map(lambda a: shape_of(a.shape, a.dtype),
-                             abstract_params(model.cache_specs(B, 128 + 16)))
-        compiled = jax.jit(model.decode, donate_argnums=(1,)).lower(
+        cache = jax.tree.map(
+            lambda a: shape_of(a.shape, a.dtype),
+            abstract_params(engine.model.cache_specs(B, 128 + 16)))
+        compiled = engine._decode.lower(
             params, cache, shape_of((B, 1), jnp.int32),
             shape_of((), jnp.int32)).compile()
     assert _total_bytes(compiled) < V5E_HBM_BYTES
