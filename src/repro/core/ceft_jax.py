@@ -35,7 +35,7 @@ relax); all formulations compute identical values (tests assert this).
 from __future__ import annotations
 
 import functools
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +43,7 @@ import numpy as np
 
 from .ceft import CeftResult, _finalize
 from .machine import Machine
+from .spans import span
 from .taskgraph import (
     TaskGraph,
     csr_level_segments,
@@ -524,26 +525,44 @@ def _fused_runs(g: TaskGraph, segs=None):
     return runs, v_b, tuple(spans)
 
 
-def _device_runs(runs):
-    """Move fused super-step tables to device (the scanned xs arrays), each
-    tagged with its layout.  Segment runs carry the host-known ``masked``
-    flag: False when no real level has padded edges (no-op run-padding
-    levels are safe unmasked — they only touch the scratch row)."""
+def _host_tables(r) -> tuple:
+    """The host arrays a fused run scans over, in dispatch order."""
+    if hasattr(r, "par"):  # FusedDenseRun
+        return r.tasks, r.par, r.pdata
+    return r.tasks, r.edge_src, r.edge_data, r.edge_seg, r.e_real
+
+
+class DeviceRun(NamedTuple):
+    """One fused super-step on the device: its layout ("dense" or "seg"),
+    the scanned tables, whether some real level has padded edge slots (the
+    segment runs' host-known ``masked`` flag; no-op run-padding levels are
+    safe unmasked, they only touch the scratch row), the [lo, hi) level span
+    it covers, and the padded edge slots it relaxes against the graph edges
+    among them, both counted on the host."""
+    layout: str
+    tables: tuple
+    masked: bool
+    levels: tuple[int, int]
+    edge_slots: int
+    real_edges: int
+
+
+def _device_runs(runs, spans) -> list[DeviceRun]:
+    """Move fused super-step tables to device (the scanned xs arrays).  A
+    dense run relaxes one slot per (child, parent) cell, a segment run every
+    padded slot of every level row, run-padding rows included."""
     out = []
-    for r in runs:
+    for r, levels in zip(runs, spans):
+        tables = tuple(jnp.asarray(a) for a in _host_tables(r))
         if hasattr(r, "par"):  # FusedDenseRun
-            out.append(
-                ("dense", jnp.asarray(r.tasks), jnp.asarray(r.par),
-                 jnp.asarray(r.pdata))
-            )
+            out.append(DeviceRun("dense", tables, False, levels,
+                                 int(r.par.size),
+                                 int(np.count_nonzero(r.par >= 0))))
         else:
             E_b = r.edge_src.shape[-1]
             masked = bool(np.any((r.e_real > 0) & (r.e_real < E_b)))
-            out.append(
-                ("seg", jnp.asarray(r.tasks), jnp.asarray(r.edge_src),
-                 jnp.asarray(r.edge_data), jnp.asarray(r.edge_seg),
-                 jnp.asarray(r.e_real), masked)
-            )
+            out.append(DeviceRun("seg", tables, masked, levels,
+                                 int(r.edge_src.size), int(r.e_real.sum())))
     return out
 
 
@@ -559,15 +578,22 @@ def _padded_sources(g: TaskGraph, v_b: int) -> np.ndarray:
 
 def _build_device_state(g: TaskGraph, segs=None):
     """Uncached build of a graph's device-side sweep state: (device runs,
-    padded sources, v_b, run level spans).  The *store* for this state lives
-    in :mod:`repro.sched.plancache` (the unified plan cache, PR 6); this
-    module only knows how to build it — callers go through
+    padded sources, v_b).  The *store* for this state lives in
+    :mod:`repro.sched.plancache` (the unified plan cache); this module
+    only knows how to build it — callers go through
     :func:`_graph_device_state` so repeated sweeps of one graph hit the
-    cache."""
-    fused, v_b, spans = _fused_runs(g, segs=segs)
-    runs = _device_runs(fused)
-    srcs = jnp.asarray(_padded_sources(g, v_b))
-    return runs, srcs, v_b, spans
+    cache.  Each run's edge work is counted here, once, on the host."""
+    with span("ceft.levels"):
+        if segs is None:
+            segs = csr_level_segments(g)
+    with span("ceft.fuse"):
+        fused, v_b, spans = _fused_runs(g, segs=segs)
+        srcs = _padded_sources(g, v_b)
+    nbytes = srcs.nbytes + sum(a.nbytes for r in fused for a in _host_tables(r))
+    with span("ceft.upload", bytes=nbytes):
+        runs = _device_runs(fused, spans)
+        srcs = jnp.asarray(srcs)
+    return runs, srcs, v_b
 
 
 def _graph_device_state(g: TaskGraph, segs=None):
@@ -575,31 +601,31 @@ def _graph_device_state(g: TaskGraph, segs=None):
     the plan cache's identity-keyed device-state store."""
     from ..sched import plancache
 
-    runs, srcs, v_b, _spans = plancache.device_state(g, segs=segs)
-    return runs, srcs, v_b
+    return plancache.device_state(g, segs=segs)
 
 
 def csr_device_inputs(g: TaskGraph, comp: np.ndarray, m: Machine, dtype=jnp.float32):
     """Bucketed fused super-step device arrays for :func:`ceft_jax_csr`.
 
     Returns (runs, comp_pad, srcs_pad, L, bw, v_b) where ``runs`` is a list
-    of stacked per-run tuples (tasks, edge_src, edge_data, edge_seg, e_real)
-    — one scanned dispatch each — and comp_pad is the (v_b+1, P)
-    execution-time table (vertex count bucketed too, so graph size does not
-    leak into the jit key).
+    of :class:`DeviceRun` — one scanned dispatch each — and comp_pad is the
+    (v_b+1, P) execution-time table (vertex count bucketed too, so graph
+    size does not leak into the jit key).
     """
     runs, srcs_pad, v_b = _graph_device_state(g)
     v, P = comp.shape
-    comp_pad = np.zeros((v_b + 1, P), np.float32)
-    comp_pad[:v] = comp
-    return (
-        runs,
-        jnp.asarray(comp_pad, dtype),
-        srcs_pad,
-        jnp.asarray(m.L, dtype),
-        jnp.asarray(m.bw, dtype),
-        v_b,
-    )
+    nbytes = np.dtype(dtype).itemsize * ((v_b + 1) * P + P + P * P)
+    with span("ceft.upload", bytes=nbytes):
+        comp_pad = np.zeros((v_b + 1, P), np.float32)
+        comp_pad[:v] = comp
+        return (
+            runs,
+            jnp.asarray(comp_pad, dtype),
+            srcs_pad,
+            jnp.asarray(m.L, dtype),
+            jnp.asarray(m.bw, dtype),
+            v_b,
+        )
 
 
 def csr_sweep(
@@ -641,22 +667,55 @@ def csr_sweep(
     keep = keep_carries is not None or resume is not None
     fns = _superstep_fns(relax, keep=keep)
     start, carry = resume if resume is not None else (0, None)
-    for r in range(start, len(runs)):
-        layout, *arrs = runs[r]
-        masked = arrs.pop() if layout == "seg" else False
-        if carry is None:  # level-0 init folded into the first dispatch
-            carry = fns[(False, layout, masked, True)](
-                comp_pad, srcs_pad, *arrs, L, bw
-            )
-        else:
-            carry = fns[(False, layout, masked, False)](
-                *carry, comp_pad, *arrs, L, bw
-            )
-        if keep_carries is not None:
-            keep_carries.append(carry)
-    if carry is None:  # single-level graph: no relaxation levels at all
-        carry = _csr_init(comp_pad, srcs_pad)
+    with _sweep_span(runs[start:]):
+        for run in runs[start:]:
+            if carry is None:  # level-0 init folded into the first dispatch
+                carry = fns[(False, run.layout, run.masked, True)](
+                    comp_pad, srcs_pad, *run.tables, L, bw
+                )
+            else:
+                carry = fns[(False, run.layout, run.masked, False)](
+                    *carry, comp_pad, *run.tables, L, bw
+                )
+            if keep_carries is not None:
+                keep_carries.append(carry)
+        if carry is None:  # single-level graph: no relaxation levels at all
+            carry = _csr_init(comp_pad, srcs_pad)
     return carry
+
+
+def _sweep_span(runs: list[DeviceRun], batch: int = 1):
+    """The ``ceft.sweep`` span over dispatching ``runs``, with their
+    host-counted edge slots and real edges (times the batch)."""
+    return span("ceft.sweep",
+                edge_slots=batch * sum(r.edge_slots for r in runs),
+                real_edges=batch * sum(r.real_edges for r in runs))
+
+
+def read_tables(carry, v: int) -> tuple[np.ndarray, ...]:
+    """Wait for a sweep's padded (ceft, pred_task, pred_proc) carry and read
+    it back to the host, keeping the first ``v`` rows (the rest are
+    scratch) of each (v_b+1, P) or batched (B, v_b+1, P) table.  The copies
+    start once the host has seen the sweep end, which keeps the wait and the
+    copies apart and costs about 0.3 ms per n=16384, P=64 plan on a TPU v5e
+    over letting the first copy wait on the device."""
+    with span("ceft.wait"):
+        jax.block_until_ready(carry)
+    with span("ceft.readback", bytes=sum(int(a.nbytes) for a in carry)):
+        return tuple(np.asarray(a)[..., :v, :] for a in carry)
+
+
+def read_plans(g: TaskGraph, carry) -> list[CeftResult]:
+    """Read a sweep's carry back (:func:`read_tables`), widen the CEFT table
+    to float64 and finalize one :class:`CeftResult` per cost plane: one for
+    a (v_b+1, P) carry, B for a batched (B, v_b+1, P) one."""
+    ceft_arr, ptask, pproc = read_tables(carry, g.n)
+    with span("ceft.finalize"):
+        ceft_arr = ceft_arr.astype(np.float64)
+        if ceft_arr.ndim == 2:
+            ceft_arr, ptask, pproc = ceft_arr[None], ptask[None], pproc[None]
+        return [_finalize(g, c, pt, pp)
+                for c, pt, pp in zip(ceft_arr, ptask, pproc)]
 
 
 def ceft_jax_csr(
@@ -668,15 +727,9 @@ def ceft_jax_csr(
     Produces values bit-identical to :func:`ceft_jax` (same float32 arithmetic
     per candidate, same tie-breaking) while doing only real-edge work.
     """
-    v = g.n
     inputs = csr_device_inputs(g, comp, m)
-    ceft_arr, ptask, pproc = csr_sweep(inputs, relax=relax)
-    return _finalize(
-        g,
-        np.asarray(ceft_arr, np.float64)[:v],
-        np.asarray(ptask)[:v],
-        np.asarray(pproc)[:v],
-    )
+    [result] = read_plans(g, csr_sweep(inputs, relax=relax))
+    return result
 
 
 # ------------------------------------------------------- batched CSR re-planning
@@ -692,16 +745,18 @@ def csr_batch_device_inputs(g: TaskGraph, comps, Ls, bws, dtype=jnp.float32):
     comps = stack_cost_planes(g, comps)
     runs, srcs_pad, v_b = _graph_device_state(g)
     B, v, P = comps.shape
-    comp_pad = np.zeros((B, v_b + 1, P), np.float32)
-    comp_pad[:, :v] = comps
-    return (
-        runs,
-        jnp.asarray(comp_pad, dtype),
-        srcs_pad,
-        jnp.asarray(np.asarray(Ls, np.float32), dtype),
-        jnp.asarray(np.asarray(bws, np.float32), dtype),
-        v_b,
-    )
+    nbytes = np.dtype(dtype).itemsize * B * ((v_b + 1) * P + P + P * P)
+    with span("ceft.upload", bytes=nbytes):
+        comp_pad = np.zeros((B, v_b + 1, P), np.float32)
+        comp_pad[:, :v] = comps
+        return (
+            runs,
+            jnp.asarray(comp_pad, dtype),
+            srcs_pad,
+            jnp.asarray(np.asarray(Ls, np.float32), dtype),
+            jnp.asarray(np.asarray(bws, np.float32), dtype),
+            v_b,
+        )
 
 
 def csr_batch_sweep(inputs, *, relax: Callable = xla_edge_relax):
@@ -713,18 +768,18 @@ def csr_batch_sweep(inputs, *, relax: Callable = xla_edge_relax):
     runs, comp_pad, srcs_pad, Ls, bws, v_b = inputs
     fns = _superstep_fns(relax)
     carry = None
-    for layout, *arrs in runs:
-        masked = arrs.pop() if layout == "seg" else False
-        if carry is None:  # level-0 init folded into the first dispatch
-            carry = fns[(True, layout, masked, True)](
-                comp_pad, srcs_pad, *arrs, Ls, bws
-            )
-        else:
-            carry = fns[(True, layout, masked, False)](
-                *carry, comp_pad, *arrs, Ls, bws
-            )
-    if carry is None:  # single-level graph: no relaxation levels at all
-        carry = _csr_init_batch(comp_pad, srcs_pad)
+    with _sweep_span(runs, batch=comp_pad.shape[0]):
+        for run in runs:
+            if carry is None:  # level-0 init folded into the first dispatch
+                carry = fns[(True, run.layout, run.masked, True)](
+                    comp_pad, srcs_pad, *run.tables, Ls, bws
+                )
+            else:
+                carry = fns[(True, run.layout, run.masked, False)](
+                    *carry, comp_pad, *run.tables, Ls, bws
+                )
+        if carry is None:  # single-level graph: no relaxation levels at all
+            carry = _csr_init_batch(comp_pad, srcs_pad)
     return carry
 
 
@@ -740,14 +795,8 @@ def ceft_jax_batch_csr(
     arrays (host-sliced from the padded carries), bit-identical to
     :func:`ceft_jax_batch`.
     """
-    v = g.n
     inputs = csr_batch_device_inputs(g, comps, Ls, bws)
-    ceft_arr, ptask, pproc = csr_batch_sweep(inputs, relax=relax)
-    return (
-        np.asarray(ceft_arr)[:, :v],
-        np.asarray(ptask)[:, :v],
-        np.asarray(pproc)[:, :v],
-    )
+    return read_tables(csr_batch_sweep(inputs, relax=relax), g.n)
 
 
 def ceft_batch_csr_results(
@@ -756,12 +805,8 @@ def ceft_batch_csr_results(
 ) -> list[CeftResult]:
     """Finalized :class:`CeftResult` per batched scenario (paper lines 19-26
     applied to each plane) — the form the re-planning schedulers consume."""
-    ceft_arr, ptask, pproc = ceft_jax_batch_csr(g, comps, Ls, bws, relax=relax)
-    ceft_np = np.asarray(ceft_arr, np.float64)
-    pt_np, pp_np = np.asarray(ptask), np.asarray(pproc)
-    return [
-        _finalize(g, ceft_np[b], pt_np[b], pp_np[b]) for b in range(ceft_np.shape[0])
-    ]
+    inputs = csr_batch_device_inputs(g, comps, Ls, bws)
+    return read_plans(g, csr_batch_sweep(inputs, relax=relax))
 
 
 # ------------------------------------------------------ in-memory request DAGs

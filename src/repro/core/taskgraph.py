@@ -19,6 +19,8 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from .spans import span
+
 
 @dataclasses.dataclass(frozen=True)
 class TaskGraph:
@@ -160,29 +162,30 @@ def from_edge_arrays(
     src = np.asarray(src, dtype=np.int32)
     dst = np.asarray(dst, dtype=np.int32)
     dat = np.asarray(data, dtype=np.float64)
-    if src.size and not (src < dst).all():
-        if not sort_topologically:
-            raise ValueError("edges must satisfy src < dst (topological ids); "
-                             "pass sort_topologically=True to relabel")
-        order = _topo_order(n, src, dst)
-        rank = np.empty(n, np.int32)
-        rank[order] = np.arange(n, dtype=np.int32)
-        src, dst = rank[src], rank[dst]
-        if not (src < dst).all():  # pragma: no cover - cycle
-            raise ValueError("graph has a cycle")
+    with span("ceft.graph"):
+        if src.size and not (src < dst).all():
+            if not sort_topologically:
+                raise ValueError("edges must satisfy src < dst (topological ids); "
+                                 "pass sort_topologically=True to relabel")
+            order = _topo_order(n, src, dst)
+            rank = np.empty(n, np.int32)
+            rank[order] = np.arange(n, dtype=np.int32)
+            src, dst = rank[src], rank[dst]
+            if not (src < dst).all():  # pragma: no cover - cycle
+                raise ValueError("graph has a cycle")
 
-    def csr(a: np.ndarray, b: np.ndarray, d: np.ndarray):
-        order = np.lexsort((b, a))
-        a, b, d = a[order], b[order], d[order]
-        indptr = np.zeros(n + 1, np.int64)
-        np.add.at(indptr, a + 1, 1)
-        np.cumsum(indptr, out=indptr)
-        return indptr, b.astype(np.int32), d
+        def csr(a: np.ndarray, b: np.ndarray, d: np.ndarray):
+            order = np.lexsort((b, a))
+            a, b, d = a[order], b[order], d[order]
+            indptr = np.zeros(n + 1, np.int64)
+            np.add.at(indptr, a + 1, 1)
+            np.cumsum(indptr, out=indptr)
+            return indptr, b.astype(np.int32), d
 
-    cindptr, cindices, cdata = csr(src, dst, dat)
-    pindptr, pindices, pdata = csr(dst, src, dat)
-    level = _levels_from_csr(n, cindptr, cindices, pindptr)
-    return TaskGraph(n, cindptr, cindices, cdata, pindptr, pindices, pdata, level)
+        cindptr, cindices, cdata = csr(src, dst, dat)
+        pindptr, pindices, pdata = csr(dst, src, dat)
+        level = _levels_from_csr(n, cindptr, cindices, pindptr)
+        return TaskGraph(n, cindptr, cindices, cdata, pindptr, pindices, pdata, level)
 
 
 def from_edges(

@@ -15,8 +15,8 @@ This module is now the single owner of that state, in three layers:
   to the SAME object, which is what makes the identity-keyed device-state
   store below hit for callers that rebuild their DAG every tick.
 * **Device-state store** (:func:`device_state`) — identity-keyed LRU holding
-  each graph's fused super-step tables on device (runs, padded sources, v_b,
-  per-run level spans).  TaskGraph is frozen/immutable and entries pin the
+  each graph's fused super-step tables on device (runs with their level
+  spans, padded sources, v_b).  TaskGraph is frozen/immutable and entries pin the
   graph object, so identity keying cannot go stale.
 * **Plan store** (:class:`PlanCache`) — (slot, planner, graph, machine)-keyed
   plans with their per-run carry snapshots, a reverse index from workload
@@ -49,9 +49,10 @@ from typing import Callable
 import numpy as np
 
 from ..core import ceft_jax, planners
-from ..core.ceft import CeftResult, _finalize
+from ..core.ceft import CeftResult
 from ..core.planners import Plan
 from ..core.machine import Machine
+from ..core.spans import span
 from ..core.taskgraph import TaskGraph, from_edge_arrays, graph_fingerprint
 
 _LOCK = threading.RLock()
@@ -95,22 +96,24 @@ def graph_for(n: int, src, dst, data) -> TaskGraph:
 
 
 def device_state(g: TaskGraph, segs=None):
-    """(device runs, padded sources, v_b, run level spans) for one graph,
-    identity-cached.  Built by :func:`ceft_jax._build_device_state`; this
-    store only owns the lifetime."""
+    """(device runs, padded sources, v_b) for one graph, identity-cached.
+    Built by :func:`ceft_jax._build_device_state`; this store only owns the
+    lifetime.  Recorded as the ``ceft.state`` span with
+    ``hit`` 1 when the store held the state, 0 when it was built."""
     key = id(g)
     with _LOCK:
         entry = _DEVICE_STATE.get(key)
         if entry is not None:
             _DEVICE_STATE.move_to_end(key)
-            return entry[1], entry[2], entry[3], entry[4]
-    built = (g,) + ceft_jax._build_device_state(g, segs=segs)
-    with _LOCK:
-        entry = _DEVICE_STATE.setdefault(key, built)
-        _DEVICE_STATE.move_to_end(key)
-        while len(_DEVICE_STATE) > DEVICE_STATE_CAP:
-            _DEVICE_STATE.popitem(last=False)
-    return entry[1], entry[2], entry[3], entry[4]
+    with span("ceft.state", hit=int(entry is not None)):
+        if entry is None:
+            built = (g,) + ceft_jax._build_device_state(g, segs=segs)
+            with _LOCK:
+                entry = _DEVICE_STATE.setdefault(key, built)
+                _DEVICE_STATE.move_to_end(key)
+                while len(_DEVICE_STATE) > DEVICE_STATE_CAP:
+                    _DEVICE_STATE.popitem(last=False)
+    return entry[1], entry[2], entry[3]
 
 
 def machine_fingerprint(m: Machine) -> bytes:
@@ -225,8 +228,6 @@ class PlanCache:
                 return result, "full", entry
 
             inputs = ceft_jax.csr_device_inputs(g, comp32, m)
-            _runs, _cp, _srcs, _L, _bw, _vb = inputs
-            _, _, _, spans = device_state(g)
             resume_run = 0
             if entry is not None and entry.comp32.shape == comp32.shape:
                 changed = np.nonzero(
@@ -236,8 +237,8 @@ class PlanCache:
                     # first run whose [lo, hi) span still contains dirty
                     # levels; runs below it (and the level-0 init) saw no
                     # comp change, so their cached carry is exact
-                    for r, (lo, hi) in enumerate(spans):
-                        if lo_level < hi:
+                    for r, run in enumerate(inputs[0]):
+                        if lo_level < run.levels[1]:
                             resume_run = r
                             break
             if resume_run >= 1 and len(entry.carries) >= resume_run:
@@ -253,14 +254,7 @@ class PlanCache:
                     inputs, relax=relax, keep_carries=carries)
                 status = "full"
                 self.counters["full_sweeps"] += 1
-            ceft_arr, ptask, pproc = carry
-            v = g.n
-            result = _finalize(
-                g,
-                np.asarray(ceft_arr, np.float64)[:v],
-                np.asarray(ptask)[:v],
-                np.asarray(pproc)[:v],
-            )
+            [result] = ceft_jax.read_plans(g, carry)
             entry = PlanEntry(
                 graph=g, machine=m, comp32=comp32.copy(), result=result,
                 carries=carries,

@@ -57,6 +57,41 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture
+def ceft_trace(tmp_path):
+    """``run(fn)`` calls ``fn`` under the JAX profiler and returns
+    ``(fn(), spans)``: the planner's ``ceft.*`` spans (repro.core.spans) as
+    (name, start_ns, end_ns, stats), in start order, parents first."""
+    import glob
+    import itertools
+
+    import jax
+    from jax.profiler import ProfileData
+
+    calls = itertools.count()
+
+    def run(fn):
+        out_dir = tmp_path / f"trace{next(calls)}"
+        jax.profiler.start_trace(str(out_dir))
+        try:
+            out = fn()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(out_dir / "**" / "*.xplane.pb"),
+                            recursive=True)
+        spans = []
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("ceft."):
+                        s = int(ev.start_ns)
+                        spans.append((ev.name, s, s + int(ev.duration_ns),
+                                      dict(ev.stats)))
+        return out, sorted(spans, key=lambda sp: (sp[1], -sp[2]))
+
+    return run
+
+
 def make_random_dag(n, p_edge, rng, data_range=(0.5, 5.0)):
     """Random DAG over topologically-ordered ids; every non-root vertex gets
     at least one parent so level-0 is the only source frontier."""

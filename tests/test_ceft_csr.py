@@ -241,9 +241,9 @@ def test_fusion_reduces_dispatch_count_on_deep_chain():
     comp = rng.uniform(1, 10, (65, 3))
     m = _machine(3)
     inputs = csr_device_inputs(g, comp, m)
-    runs = inputs[0]  # (layout, tasks, ...) per fused run
+    runs = inputs[0]  # one DeviceRun per fused run, tables led by tasks
     n_dispatch = len(runs)
-    n_levels_covered = sum(int(r[1].shape[0]) for r in runs)
+    n_levels_covered = sum(int(r.tables[0].shape[0]) for r in runs)
     assert n_dispatch <= 2, f"chain not fused: {n_dispatch} dispatches"
     assert n_levels_covered >= 64  # every relaxation level is inside a run
     # the fused sweep itself still matches the unfused semantics

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.core import Machine, from_edges, uniform_machine
+from repro.core import ceft_jax
 from repro.core.ceft_jax import CSR_TRACES, ceft_jax_csr
 from repro.sched import PlanCache
 from repro.sched import plancache as PC
@@ -55,7 +56,8 @@ def test_graph_splits_into_multiple_runs():
     """Precondition for everything below: the adversarial shape must produce
     >= 2 fused runs past the folded level-0 init."""
     g, _ = _layered_graph(np.random.default_rng(0))
-    _, _, _, spans = PC.device_state(g)
+    runs, _, _ = PC.device_state(g)
+    spans = [r.levels for r in runs]
     assert len(spans) >= 3, spans
     # spans tile the non-source levels contiguously from level 1
     assert spans[0][0] == 1
@@ -87,6 +89,42 @@ def test_cost_delta_resweeps_are_bit_identical(where):
     assert status2 == ("full" if where == "source" else "partial")
     _assert_bit_identical(res2, ceft_jax_csr(g, comp2, m))
     assert pc.snapshot()["hits"] == 0
+
+
+@pytest.mark.parametrize("where", ["deep", "mid"])
+def test_partial_resume_sweep_span_counts_only_resumed_runs(where,
+                                                            ceft_trace):
+    """The ceft.sweep span of a partial re-sweep counts the runs it resumes
+    and their edge work, not the cached prefix's; its read-back is a full
+    one."""
+    rng = np.random.default_rng(3)
+    g, starts = _layered_graph(rng)
+    m = _machine(8)
+    comp = rng.uniform(1, 10, (g.n, m.P))
+    pc = PlanCache()
+    pc.plan(g, comp, m)
+    comp2 = comp.copy()
+    level = {"deep": 16, "mid": 7}[where]
+    comp2[int(starts[level])] *= 1.7
+    (res, status, _), spans = ceft_trace(lambda: pc.plan(g, comp2, m))
+    assert status == "partial"
+    _assert_bit_identical(res, ceft_jax_csr(g, comp2, m))
+
+    fused, v_b, run_spans = ceft_jax._fused_runs(g)
+    start = next(r for r, (lo, hi) in enumerate(run_spans) if level < hi)
+    assert start >= 1
+    tail = fused[start:]
+    # a dense run relaxes a (R, W, D) block of parent slots, a segment run
+    # R levels of E edge slots
+    want = {"edge_slots": sum(r.par.size if hasattr(r, "par")
+                              else r.edge_src.size for r in tail),
+            "real_edges": sum(int((r.par >= 0).sum()) if hasattr(r, "par")
+                              else int(r.e_real.sum()) for r in tail)}
+    assert [(n, st) for n, _, _, st in spans if n == "ceft.sweep"] == [
+        ("ceft.sweep", want)]
+    assert [n for n, _, _, _ in spans] == [
+        "ceft.state", "ceft.upload", "ceft.sweep", "ceft.wait",
+        "ceft.readback", "ceft.finalize"]
 
 
 def test_chained_partials_and_straggler_flip_bit_identical():
