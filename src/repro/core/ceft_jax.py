@@ -257,6 +257,17 @@ def _superstep_impl(
     (0 < e_real < E_b never happens): the NEG-masking then folds away.  No-op
     levels stay safe unmasked — all their ids are the scratch row, so they
     compute garbage into scratch and touch nothing real.
+
+    Back-pointers (W_b > 1): each (child, class) winner is the first maximal
+    parent edge in edge order (``arg_edge``).  The winner is broadcast back
+    to the edges with a row gather, and the one selected edge per (child,
+    class) hands its parent id and arg-min class to one ``segment_max`` over
+    a payload that is -1 on every other edge.  Indexing the winners directly
+    (``edge_src[arg_edge]``, ``argl[arg_edge, cols]``) is a gather of W_b · P
+    single elements, which a TPU v5e runs element by element: at the
+    rgg16k-p64 buckets that cost about 15 times the relaxation it reads.
+    Child slots with no edges are padding: whatever they read lands in the
+    scratch row.
     """
     key = (tag, masked, ceft_arr.shape, tasks.shape, edge_src.shape)
     CSR_TRACES[key] = CSR_TRACES.get(key, 0) + 1
@@ -281,20 +292,27 @@ def _superstep_impl(
             # first-max tie-break equals first-max-in-edge-order
             maxk = jnp.max(minl, axis=0, keepdims=True)            # (1,P)
             arg_edge = jnp.argmax(minl, axis=0)[None, :]           # (1,P)
+            pt = edge_src[arg_edge].astype(jnp.int32)              # (1,P)
+            pl = argl[arg_edge, cols]                              # (1,P)
         else:
             maxk = jax.ops.segment_max(minl, edge_seg, num_segments=W_b)
             hit = minl == maxk[edge_seg]
             if masked:
                 hit &= valid[:, None]
-            is_first = jnp.where(
-                hit,
-                jnp.arange(E_b, dtype=jnp.int32)[:, None],
-                jnp.int32(E_b),
-            )
+            edge_ids = jnp.arange(E_b, dtype=jnp.int32)[:, None]
+            is_first = jnp.where(hit, edge_ids, jnp.int32(E_b))
             arg_edge = jax.ops.segment_min(is_first, edge_seg, num_segments=W_b)
-            arg_edge = jnp.minimum(arg_edge, E_b - 1)              # (W,P)
-        pt = edge_src[arg_edge].astype(jnp.int32)                  # (W,P)
-        pl = argl[arg_edge, cols]                                  # (W,P)
+            # the winning edge of each (child, class), read through one
+            # segmented max over [parent id | arg-min class] (E, 2P) rows
+            sel = arg_edge[edge_seg] == edge_ids                   # (E,P)
+            src = jnp.broadcast_to(edge_src[:, None], sel.shape)
+            payload = jnp.where(
+                jnp.concatenate([sel, sel], axis=1),
+                jnp.concatenate([src.astype(jnp.int32), argl], axis=1),
+                jnp.int32(-1),
+            )
+            back = jax.ops.segment_max(payload, edge_seg, num_segments=W_b)
+            pt, pl = back[:, :P], back[:, P:]                      # (W,P)
         newv = comp_pad[tasks] + maxk
         ceft_arr = ceft_arr.at[tasks].set(newv, mode="drop")
         ptask = ptask.at[tasks].set(pt, mode="drop")

@@ -117,6 +117,81 @@ def test_tie_breaking_matches_reference():
     np.testing.assert_array_equal(csr.pred_proc, ref.pred_proc)
 
 
+def _tied_segment_graph(masked: bool):
+    """A graph whose exactly-tied parents reach the segment-layout body with
+    several children per level (W_b > 1).
+
+    A 20-level chain fills the first fused run.  Then a row of ``S`` tasks
+    hangs off the chain's tail, alternating comp profiles A = [3, 9] and
+    B = [9, 2].  With unit data on a homogeneous machine, an A and a B
+    parent tie for class 0 with different arg-min classes (0 and 1).  Tie
+    level 1 gives its five children 12, 3, 3, 3 and 3 such parents (24
+    edges).  With ``masked``, S = 12 and a second tie level follows: its
+    children have 8, 2, 2, 2 and 3 parents, all tie level 1 plus dominated
+    row tasks (17 edges).  The run then holds one level without padded edge
+    slots and two with them.  Without it, S = 24 and every level of the run
+    fills its 24 edge slots.  Returns (graph, comp, first tie-level row)."""
+    chain = 20
+    S = 12 if masked else 24
+    row = list(range(chain, chain + S))
+    l1 = list(range(chain + S, chain + S + 5))
+    edges = [(i, i + 1, 1.0) for i in range(chain - 1)]
+    edges += [(chain - 1, t, 1.0) for t in row]
+    for child, parents in zip(l1, [row[:12], row[:3], row[3:6], row[6:9],
+                                   row[9:12]]):
+        edges += [(p, child, 1.0) for p in parents]
+    n = l1[-1] + 1
+    if masked:
+        l2 = list(range(n, n + 5))
+        for child, parents in zip(l2, [l1 + row[:3], l1[:2], l1[1:3],
+                                       l1[2:4], l1[2:]]):
+            edges += [(p, child, 1.0) for p in sorted(parents)]
+        n = l2[-1] + 1
+    comp = np.ones((n, 2))
+    comp[row] = [[3.0, 9.0] if i % 2 == 0 else [9.0, 2.0]
+                 for i in range(S)]
+    return from_edges(n, edges), comp, l1[0]
+
+
+@pytest.mark.parametrize("masked", [True, False])
+def test_tie_breaking_on_segment_path_matches_reference(masked):
+    """Exactly-tied parents of several children per level, read through the
+    segment layout's back-pointer selection (W_b > 1), in a masked and an
+    unmasked run: the first maximal parent in ascending-id order wins, in a
+    single sweep, a batched sweep and a plan-cache resume alike."""
+    from repro.core.ceft_jax import ceft_batch_csr_results
+    from repro.sched import PlanCache
+
+    g, comp, l1 = _tied_segment_graph(masked)
+    m = uniform_machine(2, bw=1.0, L=0.0)
+    runs = csr_device_inputs(g, comp, m)[0]
+    tie_run = runs[-1]
+    assert len(runs) == 2 and tie_run.layout == "seg"
+    assert tie_run.masked == masked and tie_run.tables[0].shape[-1] > 1
+
+    comp2 = comp.copy()
+    comp2[l1:l1 + 5] += 1.0  # tie level 1 only: its run resumes
+    pc = PlanCache()
+    pc.plan(g, comp, m)
+    resumed, status, _ = pc.plan(g, comp2, m)
+    assert status == "partial"
+    batched = ceft_batch_csr_results(
+        g, np.stack([comp, comp2]), np.stack([m.L, m.L]),
+        np.stack([m.bw, m.bw]))
+    for c, got in [(comp, ceft_jax_csr(g, comp, m)), (comp, batched[0]),
+                   (comp2, batched[1]), (comp2, resumed)]:
+        ref = ceft_reference(g, c, m)
+        pad = ceft_jax(g, c, m)
+        for want in (ref, pad):
+            np.testing.assert_array_equal(got.pred_task, want.pred_task)
+            np.testing.assert_array_equal(got.pred_proc, want.pred_proc)
+            assert got.path == want.path
+        # class 0 of the first child ties across all twelve parents, A (via
+        # class 0) and B (via class 1): the first, an A, must win
+        assert (got.pred_task[l1, 0], got.pred_proc[l1, 0]) == (
+            g.parents(l1)[0], 0)
+
+
 @given(st.integers(0, 10_000))
 def test_csr_matches_reference_random(seed):
     rng = np.random.default_rng(seed)

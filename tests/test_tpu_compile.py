@@ -13,6 +13,7 @@ process at a time may load the TPU library, and a worker that cannot skips
 here instead of failing collection for every worker.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -84,22 +85,40 @@ def test_pallas_kernel_compiles_for_v5e(kernel, shape_of):
 V_B, R, W_B, E_B, D_B = 16384, 192, 192, 1024, 8
 
 
-@pytest.mark.parametrize("layout", ["seg", "dense"])
-def test_csr_superstep_compiles_for_v5e(layout, shape_of):
-    S = shape_of
+def _csr_superstep(S, layout: str, masked: bool = True):
+    """The CSR super-step compiled at these buckets for a described v5e."""
     i32 = jnp.int32
     fns = _superstep_fns_for(xla_edge_relax, "tpu")
     carry = (S((V_B + 1, P)), S((V_B + 1, P), i32), S((V_B + 1, P), i32))
     if layout == "seg":
         run = (S((R, W_B), i32), S((R, E_B), i32), S((R, E_B)),
                S((R, E_B), i32), S((R,), i32))
-        fn = fns[(False, "seg", True, False)]
+        fn = fns[(False, "seg", masked, False)]
     else:
         run = (S((R, W_B), i32), S((R, W_B, D_B), i32), S((R, W_B, D_B)))
         fn = fns[(False, "dense", False, False)]
-    compiled = fn.lower(*carry, S((V_B + 1, P)), *run, S((P,)),
-                        S((P, P))).compile()
+    return fn.lower(*carry, S((V_B + 1, P)), *run, S((P,)),
+                    S((P, P))).compile()
+
+
+@pytest.mark.parametrize("layout", ["seg", "dense"])
+def test_csr_superstep_compiles_for_v5e(layout, shape_of):
+    compiled = _csr_superstep(shape_of, layout)
     assert compiled.memory_analysis().argument_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("masked", [True, False])
+def test_seg_superstep_has_no_element_gathers(masked, shape_of):
+    """The segment layout reads its back-pointers without gathering single
+    elements: a v5e runs such a gather element by element, and the W_B x P
+    of them per level once took most of the sweep's device time.  Only row
+    gathers (a whole P-wide row per index) may remain."""
+    hlo = _csr_superstep(shape_of, "seg", masked).as_text()
+    gathers = [line for line in hlo.splitlines() if " gather(" in line]
+    assert gathers, "no gather at all: the pattern below matches nothing"
+    element = [g for g in gathers
+               if re.search(r"slice_sizes=\{1(,1)*\}", g)]
+    assert not element, element
 
 
 def _total_bytes(compiled) -> int:
