@@ -6,6 +6,7 @@ import contextlib
 import json
 import shutil
 import sys
+import threading
 import time
 from pathlib import Path
 
@@ -98,13 +99,17 @@ class Spans:
 
 class Tracer:
     """The profiler around the first ``seconds`` of the window (off when
-    ``seconds`` is 0)."""
+    ``seconds`` is 0).  Writing the trace takes the host tens of seconds.
+    A closed loop may pause for it between calls; an open loop's client
+    would stop submitting while requests fall due, so ``stop(background=
+    True)`` writes on a thread of its own, and ``wait`` joins it."""
 
     def __init__(self, workload: str, seconds: float):
         self.dir = TRACE_DIR / workload
         self.seconds = seconds
         self.on = False
         self.t1 = 0
+        self.writer = None
 
     def start(self) -> None:
         import jax
@@ -113,18 +118,31 @@ class Tracer:
             return
         shutil.rmtree(self.dir, ignore_errors=True)
         self.dir.mkdir(parents=True, exist_ok=True)
-        jax.profiler.start_trace(str(self.dir))
+        # no Python function tracer: host spans (TraceAnnotation) and device
+        # operations are all the readers take, and tracing every Python
+        # call lengthens the host's gaps between a tick's decode steps
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(self.dir), profiler_options=opts)
         self.on = True
 
     def due(self, elapsed_s: float) -> bool:
         return self.on and self.t1 == 0 and elapsed_s >= self.seconds
 
-    def stop(self) -> None:
+    def stop(self, background: bool = False) -> None:
         import jax
 
         if self.on and self.t1 == 0:
             self.t1 = time.perf_counter_ns()
-            jax.profiler.stop_trace()
+            if not background:
+                jax.profiler.stop_trace()
+                return
+            self.writer = threading.Thread(target=jax.profiler.stop_trace)
+            self.writer.start()
+
+    def wait(self) -> None:
+        if self.writer is not None:
+            self.writer.join()
 
 
 def percentile(values, i: int) -> float:

@@ -76,18 +76,29 @@ def latencies_s(rec):
     return out
 
 
+def req_p50_ms(rec):
+    lat = latencies_s(rec)
+    return 1e3 * percentile(lat, 50) if lat else None
+
+
 def req_p90_ms(rec):
     lat = latencies_s(rec)
     return 1e3 * percentile(lat, 90) if lat else None
 
 
 def tokens_per_s(rec):
+    """Output tokens of every request due in the window, over the seconds
+    from the window's start until the last of them completed (the window's
+    length, if that is longer): all the work the window offered and all the
+    time it took.  A router tick hands back its answers when it ends, so a
+    count cut at the window's end would swing with where the last tick
+    ends; a request never completed adds no tokens."""
     if "requests" not in rec:
         return None
-    toks = sum(r["max_new"] for r in rec["requests"]
-               if not r["rejected"] and r["done"] is not None
-               and r["done"] <= rec["window_s"])
-    return toks / rec["window_s"]
+    done = [r for r in rec["requests"]
+            if not r["rejected"] and r["done"] is not None]
+    end = max([rec["window_s"]] + [r["done"] for r in done])
+    return sum(r["max_new"] for r in done) / end
 
 
 def batch_size(rec):

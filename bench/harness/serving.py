@@ -158,7 +158,7 @@ def run(ctx) -> dict:
     while True:
         now = time.perf_counter() - t0
         if tracer.due(now):
-            tracer.stop()
+            tracer.stop(background=True)
         with spans("submit"):
             while nxt < len(recs) and recs[nxt]["due"] <= now:
                 r = recs[nxt]

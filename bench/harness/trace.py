@@ -104,31 +104,37 @@ def count_modules(modules, prefix: str) -> tuple[int, int]:
 
 
 def load(trace_dir: str, span_names) -> dict:
-    """Read the newest ``.xplane.pb`` under ``trace_dir``.
-
-    Returns {"ops", "modules", "spans", "n_devices"}: device operations and
-    whole-program events of every accelerator plane, and the host spans whose
-    names are in ``span_names``, each as (name, start_ns, end_ns)."""
+    """Read the newest ``.xplane.pb`` under ``trace_dir`` and reduce it
+    (``reduce``)."""
     from jax.profiler import ProfileData
 
     files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
     if not files:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    pd = ProfileData.from_file(files[-1])
+    return reduce(ProfileData.from_file(files[-1]).planes, span_names)
+
+
+def reduce(planes, span_names) -> dict:
+    """{"ops", "modules", "spans", "n_devices"} of a trace's planes: device
+    operations and whole-program events of every accelerator plane, and the
+    host spans whose names are in ``span_names``, each as (name, start_ns,
+    end_ns).  An accelerator counts in ``n_devices`` only where it ran
+    operations: a TPU trace also holds a ``/device:CUSTOM:...`` plane with
+    no operation line."""
     wanted = set(span_names)
     ops, modules, spans = [], [], []
     devices = 0
-    for plane in pd.planes:
+    for plane in planes:
         is_device = (plane.name.startswith("/device:")
                      and not plane.name.startswith("/device:CPU"))
-        devices += is_device
         lines = list(plane.lines)
         names = {line.name for line in lines}
         # single operations: the "XLA Ops" line, or any line of operations
         # where a device names it otherwise
         op_lines = ({OPS_LINE} if OPS_LINE in names else
                     {n for n in names if "Ops" in n})
+        devices += is_device and bool(op_lines)
         for line in lines:
             if is_device and (line.name in op_lines
                               or line.name == MODULES_LINE):

@@ -3,7 +3,10 @@
 Every seed gets the same work: the counts of each size and tenant follow the
 file's shares exactly (largest-remainder rounding), and only their order and
 the arrival times come from the seed.  So runs on different seeds differ in
-arrangement, not in the amount of work.
+arrangement, not in the amount of work.  A file that names a
+``pattern_seed`` fixes the arrangement too (arrival times, tenants and
+sizes, drawn from that seed); the run's seed then draws the prompts' tokens
+alone, as a replayed trace does.
 """
 from __future__ import annotations
 
@@ -62,11 +65,13 @@ def open_loop(traffic: dict, seconds: float, vocab: int, seed: int) -> list:
     from the window's start), ``tenant``, ``prompt`` (int32 ids in
     [2, vocab)) and ``max_new``, sorted by ``due``."""
     rng = np.random.default_rng(seed)
+    pattern = traffic.get("pattern_seed")
+    arrange = rng if pattern is None else np.random.default_rng(pattern)
     n = int(round(traffic["rate_per_s"] * seconds))
-    due = arrival_times(n, seconds, rng, traffic.get("bursts"))
-    tenants = shuffled_labels(traffic["tenants"], n, rng)
-    plens = shuffled_labels(traffic["prompt_len"], n, rng)
-    news = shuffled_labels(traffic["max_new"], n, rng)
+    due = arrival_times(n, seconds, arrange, traffic.get("bursts"))
+    tenants = shuffled_labels(traffic["tenants"], n, arrange)
+    plens = shuffled_labels(traffic["prompt_len"], n, arrange)
+    news = shuffled_labels(traffic["max_new"], n, arrange)
     out = []
     for i in range(n):
         plen = int(plens[i])

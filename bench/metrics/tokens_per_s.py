@@ -1,4 +1,4 @@
-"""Output tokens of requests completed within the window, per second."""
+"""Output tokens of the window's requests per second, over the window and its drain."""
 from harness import readers
 
 

@@ -118,6 +118,7 @@ def main(argv=None) -> int:
     device = dict(device, memory_peak_bytes=out["memory_peak_bytes"])
     breakdown = None
     if args.trace:
+        ctx.tracer.wait()
         tr = tracing.load(str(ctx.tracer.dir), SPAN_NAMES)
         lo = min(s for _, s, _ in tr["spans"])
         hi = max(e for _, _, e in tr["spans"])

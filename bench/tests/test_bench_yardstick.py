@@ -117,6 +117,21 @@ def test_every_seed_offers_the_same_work(seeds):
     np.testing.assert_allclose(gaps[0], gaps[1], rtol=1e-9)
 
 
+def test_pattern_seed_fixes_the_arrangement_not_the_prompts():
+    """With ``pattern_seed`` every run seed replays one arrangement of
+    arrival times, tenants and sizes; the prompts' tokens still follow the
+    run's seed."""
+    fixed = dict(CHAT, pattern_seed=0)
+    a, b = (traffic.open_loop(fixed, 50, 1000, s) for s in (1, 2**31 + 5))
+    for key in ("due", "tenant", "max_new"):
+        assert [r[key] for r in a] == [r[key] for r in b]
+    assert [r["prompt"].size for r in a] == [r["prompt"].size for r in b]
+    assert not all((x["prompt"] == y["prompt"]).all() for x, y in zip(a, b))
+    # the arrangement is the one a run of the pattern's own seed draws
+    plain = traffic.open_loop(CHAT, 50, 1000, 0)
+    assert [r["due"] for r in a] == [r["due"] for r in plain]
+
+
 def test_shapes_cover_every_batch_and_size():
     assert sorted(traffic.shapes(CHAT, 2)) == sorted(
         (b, p, m) for b in (1, 2) for p in (128, 192) for m in (16, 64))
@@ -141,3 +156,74 @@ def test_dense_lm_counts_by_hand():
         counts.dense_lm_token_ops(CFG, 1, False)
         + counts.dense_lm_token_ops(CFG, 2, True)
         + counts.dense_lm_token_ops(CFG, 3, True))
+
+
+def test_serving_readers_by_hand():
+    """Due-to-done tails count a request never completed as infinite;
+    tokens per second take every completed request of the window over the
+    time until the last of them completed."""
+    def req(due, done, new, rejected=False):
+        return {"due": due, "done": done, "max_new": new,
+                "rejected": rejected}
+    recs = [req(0.0, 1.0, 16), req(1.0, 4.0, 64), req(2.0, 12.0, 16),
+            req(9.0, None, 64), req(9.5, 9.9, 16, rejected=True)]
+    rec = {"requests": recs, "window_s": 10.0}
+    assert readers.latencies_s(rec)[:3] == [1.0, 3.0, 10.0]
+    assert readers.latencies_s(rec)[3:] == [math.inf, math.inf]
+    assert readers.req_p90_ms(rec) == math.inf
+    # 16 + 64 + 16 tokens over the 12 s until the last completion
+    assert readers.tokens_per_s(rec) == pytest.approx(96 / 12.0)
+    # all done inside the window: the window's length is the time
+    rec = {"requests": recs[:2], "window_s": 10.0}
+    assert readers.tokens_per_s(rec) == pytest.approx(80 / 10.0)
+    import statistics
+
+    assert readers.req_p90_ms(rec) == pytest.approx(
+        1e3 * statistics.quantiles([1.0, 3.0], n=100)[89])
+    assert readers.req_p50_ms(rec) == pytest.approx(2000.0)
+
+
+def test_reduce_counts_only_planes_that_ran_operations():
+    """A TPU trace's planes, as the chip writes them: one TPU with its
+    operation and program lines, an empty custom device plane, and the host
+    with the benchmark's spans.  Busy time is averaged over one device."""
+    from types import SimpleNamespace as NS
+
+    def line(name, *events):
+        return NS(name=name, events=[NS(name=n, start_ns=s, duration_ns=d)
+                                     for n, s, d in events])
+    planes = [
+        NS(name="/device:TPU:0", lines=[
+            line("XLA Modules", ("jit_decode(17)", 0, 30)),
+            line("XLA Ops", ("convert", 0, 20), ("while", 20, 10)),
+            line("TC Overlay")]),
+        NS(name="/device:CUSTOM:Megascale Trace", lines=[]),
+        NS(name="/host:CPU", lines=[
+            line("", ("tick", 0, 40), ("other", 5, 1))])]
+    tr = trace.reduce(planes, ("tick",))
+    assert tr["n_devices"] == 1
+    assert tr["ops"] == [("convert", 0, 20), ("while", 20, 30)]
+    assert tr["modules"] == [("jit_decode(17)", 0, 30)]
+    assert tr["spans"] == [("tick", 0, 40)]
+    assert trace.count_modules(tr["modules"], "jit_decode") == (1, 30)
+
+
+@pytest.mark.parametrize("background", [False, True])
+def test_tracer_keeps_the_spans_of_its_window(tmp_path, background):
+    """The trace holds the spans from its start to its stop, whether the
+    stop writes it in place or on a thread that ``wait`` joins."""
+    import jax.numpy as jnp
+
+    tracer = common.Tracer("test", 1.0)
+    tracer.dir = tmp_path / "trace"
+    spans = common.Spans()
+    tracer.start()
+    for _ in range(2):
+        with spans("tick"):
+            jnp.ones(8).sum().block_until_ready()
+    tracer.stop(background=background)
+    with spans("tick"):
+        jnp.ones(8).sum().block_until_ready()
+    tracer.wait()
+    assert len(trace.load(str(tracer.dir), ("tick",))["spans"]) == 2
+    assert len(spans.items) == 3
