@@ -3,6 +3,7 @@ from . import (
     dbrx_132b,
     glm4_9b,
     granite_3_8b,
+    granite_4_0_h_small,
     jamba_v0_1_52b,
     llama3_405b,
     mamba2_2_7b,
@@ -24,6 +25,7 @@ _MODULES = {
     "mixtral-8x22b": mixtral_8x22b,
     "dbrx-132b": dbrx_132b,
     "mamba2-2.7b": mamba2_2_7b,
+    "granite-4.0-h-small": granite_4_0_h_small,
 }
 
 ARCHS = list(_MODULES)

@@ -26,6 +26,11 @@ class ArchConfig:
     top_k: int = 0
     moe_every: int = 1       # MoE replaces MLP every k-th layer (jamba: 2)
     capacity_factor: float = 1.25
+    shared_ff: int = 0       # width of a shared SwiGLU expert beside the routed ones
+    # the routed experts this chip holds: experts_held from expert_first
+    # (0 -> all n_experts); the router still scores all n_experts
+    experts_held: int = 0
+    expert_first: int = 0
     # -- SSM (Mamba-2 / SSD) --
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -49,6 +54,11 @@ class ArchConfig:
     # -- misc --
     frontend: str = "none"   # none | audio_stub | vision_stub
     tie_embeddings: bool = False
+    # -- Granite-style multipliers (1.0 / 0.0 leave a model unscaled) --
+    embedding_mult: float = 1.0   # token embeddings x this
+    residual_mult: float = 1.0    # both residual branches x this
+    attn_scale: float = 0.0       # softmax scale; 0 -> 1/sqrt(head_dim)
+    logits_div: float = 1.0       # logits / this
     norm_eps: float = 1e-5
     compute_dtype: str = "bfloat16"
     param_dtype: str = "float32"
@@ -71,6 +81,11 @@ class ArchConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def n_held(self) -> int:
+        """Routed experts this chip holds and computes."""
+        return self.experts_held or self.n_experts
+
+    @property
     def period(self) -> int:
         """Layer-pattern period for the scanned stack."""
         if self.family == "hybrid":
@@ -89,7 +104,8 @@ class ArchConfig:
             out = []
             for i in range(self.attn_every):
                 mixer = "attn" if i == self.attn_pos else "ssm"
-                channel = "moe" if (i % self.moe_every == 1) else "mlp"
+                last = i % self.moe_every == self.moe_every - 1
+                channel = "moe" if last else "mlp"
                 out.append((mixer, channel))
             return out
         raise ValueError(self.family)
@@ -112,7 +128,8 @@ class ArchConfig:
             if channel == "mlp":
                 total += mult * d * ff * reps
             elif channel == "moe":
-                total += (mult * d * ff * self.n_experts + d * self.n_experts) * reps
+                total += (mult * d * ff * self.n_held + d * self.n_experts
+                          + mult * d * self.shared_ff) * reps
         if self.family == "encdec":
             # add encoder stack (self-attn + mlp) and decoder cross-attn
             mult = 3 if self.mlp_style == "swiglu" else 2
@@ -122,16 +139,19 @@ class ArchConfig:
         return total
 
     def n_active_params(self) -> int:
-        """Parameters touched per token (MoE: top_k of n_experts)."""
+        """Parameters touched per token (MoE: the shared expert and top_k of
+        n_experts; where this chip holds a share of the experts, its
+        expected share of the top_k)."""
         if not self.n_experts:
             return self.n_params()
         d, ff = self.d_model, self.d_ff
         mult = 3 if self.mlp_style == "swiglu" else 2
         reps = self.n_layers // self.period
         moe_positions = sum(1 for _, c in self.layer_pattern() if c == "moe")
-        dense_moe = mult * d * ff * self.n_experts * moe_positions * reps
-        active_moe = mult * d * ff * self.top_k * moe_positions * reps
-        return self.n_params() - dense_moe + active_moe
+        held_moe = mult * d * ff * self.n_held * moe_positions * reps
+        active = self.top_k * self.n_held / self.n_experts
+        active_moe = round(mult * d * ff * active * moe_positions * reps)
+        return self.n_params() - held_moe + active_moe
 
 
 @dataclasses.dataclass(frozen=True)

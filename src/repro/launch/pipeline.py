@@ -28,7 +28,7 @@ from ..substrate import shard_map
 def _stage_fwd(cfg: ArchConfig, stage_params, x, cos_sin):
     """Apply this device's layers (stacked on axis 0) to x."""
     def body(h, pp):
-        h2, _, _ = _period_fwd(cfg, pp, h, cos_sin)
+        h2, _, _, _ = _period_fwd(cfg, pp, h, cos_sin)
         return h2, None
     out, _ = jax.lax.scan(body, x, stage_params)
     return out

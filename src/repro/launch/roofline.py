@@ -332,7 +332,7 @@ def build_probes(cfg: ArchConfig, cell: ShapeCell, mesh) -> list[Probe]:
         specs = {"norm": rmsnorm_spec(D), **moe_specs(cfg)}
 
         def moe_block(p, x):
-            y, aux = moe(p, rmsnorm(p["norm"], x, cfg.norm_eps), cfg)
+            y, aux, _ = moe(p, rmsnorm(p["norm"], x, cfg.norm_eps), cfg)
             return x + y + aux
 
         add("moe_block", moe_block, specs, (tok_abs,), (tok_sh,), n_moe, train)

@@ -114,11 +114,13 @@ def chunked_attention(
     kv_len: Optional[int] = None,
     q_chunk: int = 512,
     k_chunk: int = 1024,
+    scale: Optional[float] = None,
 ):
     """Flash-style online-softmax attention in pure XLA: O(S) memory.
 
     q: (B, Hkv, G, Sq, hd); k, v: (B, Sk, Hkv, hd).
     kv_len: number of valid keys (<= Sk) for padded caches.
+    scale: softmax scale (default 1/sqrt(hd)).
     Never materializes (Sq, Sk); the working set is (qc, kc) score tiles --
     exactly the shape XLA:TPU fuses into VMEM-resident loops.
     """
@@ -135,7 +137,7 @@ def chunked_attention(
         k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
     nq, nk = (Sq + pad_q) // qc, (Sk + pad_k) // kc
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     kT = jnp.moveaxis(k, 1, 3)  # (B, Hkv, hd, Skp)
     vT = jnp.moveaxis(v, 1, 2)  # (B, Hkv, Skp, hd)
 
@@ -180,6 +182,11 @@ def chunked_attention(
     return out[:, :, :, :Sq]
 
 
+def attn_scale(cfg: ArchConfig) -> float:
+    """The softmax scale: the configuration's, else 1/sqrt(head_dim)."""
+    return cfg.attn_scale or 1.0 / math.sqrt(cfg.hd)
+
+
 def attn_prefill(p, x, cfg: ArchConfig, cos_sin, *, window: int = 0, causal=True):
     """Full-sequence attention; returns (out, (k, v)) for cache seeding."""
     B, S, D = x.shape
@@ -188,7 +195,8 @@ def attn_prefill(p, x, cfg: ArchConfig, cos_sin, *, window: int = 0, causal=True
     q = constrain(q, "batch", None, "heads", None)
     k = constrain(k, "batch", None, "heads", None)
     qh = jnp.moveaxis(q.reshape(B, S, hkv, hq // hkv, hd), 1, 3)  # (B,Hkv,G,S,hd)
-    out = chunked_attention(qh, k, v, causal=causal, window=window)
+    out = chunked_attention(qh, k, v, causal=causal, window=window,
+                            scale=attn_scale(cfg))
     out = jnp.moveaxis(out, 3, 1).reshape(B, S, hq * hd)
     out = out @ p["wo"].astype(x.dtype)
     return constrain(out, "batch", "seq", None), (k, v)
@@ -213,8 +221,7 @@ def attn_decode(p, x, cfg: ArchConfig, cache, pos, cos_sin, *, window: int = 0):
     ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
     cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
     qh = jnp.moveaxis(q.reshape(B, 1, hkv, hq // hkv, hd), 1, 3)  # (B,Hkv,G,1,hd)
-    scale = 1.0 / math.sqrt(hd)
-    s = jnp.einsum("bhgqd,bshd->bhgqs", qh, ck.astype(qh.dtype)) * scale
+    s = jnp.einsum("bhgqd,bshd->bhgqs", qh, ck.astype(qh.dtype)) * attn_scale(cfg)
     s = s.astype(jnp.float32)
     idx = jnp.arange(Sc)
     valid = idx < jnp.minimum(pos + 1, Sc) if window > 0 else idx <= pos

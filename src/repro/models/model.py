@@ -68,6 +68,7 @@ class Model:
             embeds=batch.get("embeds"),
             positions=batch.get("positions"),
             want_cache=True,
+            dropless=True,
         )
         logits = transformer.unembed(params, cfg, hidden[:, -1:])
         return cache, logits

@@ -6,6 +6,13 @@ channel-mixer) pair per *period position*; parameters are stacked over periods
 and the stack runs as one ``lax.scan`` -> HLO size is O(period), not O(layers)
 (llama3-405b compiles as a 126-iteration scan; jamba as 4 periods of 8
 heterogeneous layers unrolled).
+
+Granite-style multipliers (``embedding_mult``, ``residual_mult``,
+``logits_div``; ``attn_scale`` in layers.py) apply only where a
+configuration sets them, so other models trace to the programs they had.
+A model with an expert layer carries ``SLOTS`` in its cache: the (token,
+expert) slot counts of ``moe`` (held, routed, dropped) summed since the
+prefill, counted on the device.
 """
 from __future__ import annotations
 
@@ -29,6 +36,8 @@ from .layers import (
 )
 from .moe import moe, moe_specs
 from .ssm import ssd_decode, ssd_prefill, ssm_specs
+
+SLOTS = "moe_slots"
 
 
 def stack_specs(tree, n: int):
@@ -95,7 +104,15 @@ def cache_specs(cfg: ArchConfig, batch: int, seq: int) -> dict:
                               ("layers", "cache_batch", "none", "ssm_inner"),
                               init="zeros", dtype=cfg.compute_dtype),
             }
+    if any(c == "moe" for _, c in cfg.layer_pattern()):
+        out[SLOTS] = PSpec((3,), ("none",), init="zeros", dtype="int32")
     return out
+
+
+def _residual(cfg: ArchConfig, x, branch):
+    if cfg.residual_mult != 1.0:
+        branch = branch * cfg.residual_mult
+    return x + branch
 
 
 # ---------------------------------------------------------------------- forward
@@ -104,18 +121,24 @@ def embed_tokens(params, cfg: ArchConfig, tokens=None, embeds=None):
         x = embeds.astype(jnp.dtype(cfg.compute_dtype))
     else:
         x = params["embed"][tokens].astype(jnp.dtype(cfg.compute_dtype))
+    if cfg.embedding_mult != 1.0:
+        x = x * cfg.embedding_mult
     return constrain(x, "batch", "seq", None)
 
 
 def unembed(params, cfg: ArchConfig, x):
     w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
     logits = (x @ w.astype(x.dtype)).astype(jnp.float32)
+    if cfg.logits_div != 1.0:
+        logits = logits / cfg.logits_div
     return constrain(logits, "batch", "seq", "vocab")
 
 
-def _period_fwd(cfg: ArchConfig, pp, x, cos_sin):
-    """Full-seq forward through one period; returns (x, aux, cache_updates)."""
+def _period_fwd(cfg: ArchConfig, pp, x, cos_sin, dropless: bool = False):
+    """Full-seq forward through one period; returns (x, aux, cache_updates,
+    slot counts of its expert layers (None without one))."""
     aux = jnp.zeros((), jnp.float32)
+    slots = None
     cache_out = {}
     for i, (mixer, channel) in enumerate(cfg.layer_pattern()):
         b = pp[f"pos{i}"]
@@ -126,22 +149,27 @@ def _period_fwd(cfg: ArchConfig, pp, x, cos_sin):
         else:
             a, st = ssd_prefill(b["ssm"], h, cfg)
             cache_out[f"pos{i}"] = st
-        x = x + a
+        x = _residual(cfg, x, a)
         if channel != "none":
             h2 = rmsnorm(b["norm2"], x, cfg.norm_eps)
             if channel == "mlp":
-                x = x + mlp(b["mlp"], h2, cfg)
+                x = _residual(cfg, x, mlp(b["mlp"], h2, cfg))
             else:
-                y, a_loss = moe(b["moe"], h2, cfg)
-                x = x + y
+                y, a_loss, n = moe(b["moe"], h2, cfg, dropless)
+                x = _residual(cfg, x, y)
                 aux = aux + a_loss
+                slots = n if slots is None else slots + n
         x = constrain(x, "batch", "seq", None)
-    return x, aux, cache_out
+    return x, aux, cache_out, slots
 
 
 def forward_full(params, cfg: ArchConfig, *, tokens=None, embeds=None,
-                 positions=None, want_cache: bool = False):
-    """Training / prefill forward.  Returns (hidden (B,S,D), aux, cache|None)."""
+                 positions=None, want_cache: bool = False,
+                 dropless: bool = False):
+    """Training / prefill forward.  Returns (hidden (B,S,D), aux, cache|None).
+
+    ``dropless`` routes every (token, expert) slot (serving prefill; see
+    moe.py); the cache then also holds the expert layers' ``SLOTS``."""
     x = embed_tokens(params, cfg, tokens, embeds)
     B, S, _ = x.shape
     has_attn = any(m == "attn" for m, _ in cfg.layer_pattern())
@@ -153,14 +181,18 @@ def forward_full(params, cfg: ArchConfig, *, tokens=None, embeds=None,
 
     def body(carry, pp):
         x, aux = carry
-        x2, a, cache = _period_fwd(cfg, pp, x, cos_sin)
-        return (x2, aux + a), (cache if want_cache else 0)
+        x2, a, cache, slots = _period_fwd(cfg, pp, x, cos_sin, dropless)
+        return (x2, aux + a), ((cache if want_cache else 0), slots)
 
     body_fn = jax.checkpoint(body) if cfg.remat == "full" else body
-    (x, aux), caches = jax.lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
-                                    params["blocks"])
+    (x, aux), (caches, slots) = jax.lax.scan(
+        body_fn, (x, jnp.zeros((), jnp.float32)), params["blocks"])
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return x, aux, (caches if want_cache else None)
+    if not want_cache:
+        return x, aux, None
+    if slots is not None:
+        caches[SLOTS] = slots.sum(0)
+    return x, aux, caches
 
 
 def decode_step(params, cfg: ArchConfig, cache, *, tokens=None, embeds=None,
@@ -179,6 +211,7 @@ def decode_step(params, cfg: ArchConfig, cache, *, tokens=None, embeds=None,
     def body(x, scanned):
         pp, pc = scanned
         new_pc = {}
+        slots = None
         for i, (mixer, channel) in enumerate(cfg.layer_pattern()):
             b = pp[f"pos{i}"]
             h = rmsnorm(b["norm1"], x, cfg.norm_eps)
@@ -188,17 +221,22 @@ def decode_step(params, cfg: ArchConfig, cache, *, tokens=None, embeds=None,
             else:
                 a, nc = ssd_decode(b["ssm"], h, cfg, pc[f"pos{i}"])
             new_pc[f"pos{i}"] = nc
-            x = x + a
+            x = _residual(cfg, x, a)
             if channel != "none":
                 h2 = rmsnorm(b["norm2"], x, cfg.norm_eps)
                 if channel == "mlp":
-                    x = x + mlp(b["mlp"], h2, cfg)
+                    x = _residual(cfg, x, mlp(b["mlp"], h2, cfg))
                 else:
-                    y, _ = moe(b["moe"], h2, cfg)
-                    x = x + y
-        return x, new_pc
+                    y, _, n = moe(b["moe"], h2, cfg)
+                    x = _residual(cfg, x, y)
+                    slots = n if slots is None else slots + n
+        return x, (new_pc, slots)
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+    layers = {k: v for k, v in cache.items() if k != SLOTS}
+    x, (new_cache, slots) = jax.lax.scan(body, x, (params["blocks"], layers))
+    if SLOTS in cache:
+        new_cache[SLOTS] = cache[SLOTS] + slots.sum(0).astype(
+            cache[SLOTS].dtype)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return unembed(params, cfg, x), new_cache
 

@@ -6,7 +6,8 @@ program that does not fit the chip's memory.  These tests compile, for one
 chip of a described ``v5e:2x2`` topology, the Pallas kernels at the shapes
 their ``ops.py`` wrappers produce on a TPU, the CSR super-steps at the
 buckets of the paper's largest RGG (n=16384 tasks, P=64 classes), and the
-serving engine's minicpm-2b prefill and decode steps at published width.
+serving engine's minicpm-2b and granite-4.0-h-small prefill and decode
+steps at published width.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and a worker that cannot skips
@@ -25,6 +26,7 @@ from repro.core.ceft_jax import _superstep_fns_for, xla_edge_relax
 from repro.kernels import ops
 from repro.models.common import abstract_params
 from repro.models.model import build
+from repro.models.transformer import SLOTS
 from repro.serve.engine import Engine
 
 V5E_HBM_BYTES = 15.75 * 2**30  # what the compiler reports usable on one v5e
@@ -148,6 +150,32 @@ def test_minicpm_engine_step_fits_one_v5e(step, shape_of):
         cache = jax.tree.map(
             lambda a: shape_of(a.shape, a.dtype),
             abstract_params(engine.model.cache_specs(B, 128 + 16)))
+        compiled = engine._decode.lower(
+            params, cache, shape_of((B, 1), jnp.int32),
+            shape_of((), jnp.int32)).compile()
+    assert _total_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_granite_hybrid_engine_step_fits_one_v5e(step, shape_of):
+    """granite-4.0-h-small's chip share (4.42 B bfloat16 parameters, 18
+    Mamba-2 and 2 attention layers, 9 held experts of 72 each) at the
+    serving cell's largest shapes: prefill of 4 x 1024-token prompts with
+    dropless experts and four SSD chunks, and decode at batch 4 against a
+    1280-token cache with the expert-slot counter carried."""
+    cfg = C.get("granite-4.0-h-small")
+    params = jax.tree.map(lambda a: shape_of(a.shape, a.dtype),
+                          build(cfg).abstract())
+    engine = Engine(cfg, params=params)
+    B = 4
+    if step == "prefill":
+        compiled = engine._prefill.lower(
+            params, {"tokens": shape_of((B, 1024), jnp.int32)}).compile()
+    else:
+        cache = jax.tree.map(
+            lambda a: shape_of(a.shape, a.dtype),
+            abstract_params(engine.model.cache_specs(B, 1024 + 256)))
+        cache[SLOTS] = shape_of((3,), jnp.int32)
         compiled = engine._decode.lower(
             params, cache, shape_of((B, 1), jnp.int32),
             shape_of((), jnp.int32)).compile()
